@@ -2,6 +2,10 @@
 
     PYTHONPATH=src python -m benchmarks.run [--quick] [--only NAME]
 
+Every benchmark runs in this one process (``weak_scaling`` uses the
+process's own devices; on a CPU host give it virtual ones with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``).
+
 Prints each benchmark's CSV block plus a trailing summary in
 ``name,us_per_call,derived`` form, and writes the same summary as
 machine-readable JSON to ``BENCH_bench.json`` (the file the perf
@@ -12,6 +16,7 @@ import argparse
 import time
 import traceback
 
+from repro.compile_cache import enable_compile_cache
 from repro.ioutil import atomic_write_json
 
 from benchmarks import (
@@ -50,6 +55,7 @@ def main() -> None:
     ap.add_argument("--json", default=SUMMARY_JSON,
                     help="summary JSON output path")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.only and args.only not in {n for n, _ in BENCHES}:
         raise SystemExit(

@@ -1,38 +1,41 @@
 """Paper §6.1: distributed masked-sparse-training overhead (weak scaling).
 
-Spawns subprocesses with 1..8 fake host devices (fixed per-device batch) and
-measures dense vs masked-sparse step time including gradient sync, reporting
-scaling efficiency and the sparse-over-dense overhead — the CPU-scale
-analogue of the paper's 128-GPU Piz Daint experiment.
+Runs in one process over ``jax.devices()[:n]`` for n in 1..8 (fixed
+per-device batch) and measures dense vs masked-sparse step time including
+gradient sync, reporting scaling efficiency and the sparse-over-dense
+overhead — the analogue of the paper's 128-GPU Piz Daint experiment.
+
+It starts no child process: the process that touched JAX holds the
+devices.  On a CPU host, give it virtual devices from the caller:
+
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+        PYTHONPATH=src python benchmarks/weak_scaling.py
 """
 
-import os
-import subprocess
-import sys
-import textwrap
+import time
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
+import jax
 
-_WORKER = """
-    import time, functools
-    import jax, jax.numpy as jnp
-    from repro.configs import get_smoke
-    from repro.core.builder import SparsityBuilder
-    from repro.core.layouts import FixedMaskTensor
-    from repro.core.sparsifiers import ScalarFractionSparsifier
-    from repro.dist.sharding import ShardingRules, param_specs, tree_shardings
-    from repro.launch import steps as steps_mod
-    from repro.launch.mesh import make_host_mesh
-    from repro.models import init_lm
-    from repro.optim import AdamWConfig, adamw_init
+from repro.configs import get_smoke
+from repro.core.builder import SparsityBuilder
+from repro.core.layouts import FixedMaskTensor
+from repro.core.sparsifiers import ScalarFractionSparsifier
+from repro.dist.sharding import ShardingRules
+from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_host_mesh
+from repro.models import init_lm
+from repro.optim import AdamWConfig, adamw_init
 
-    ndev = len(jax.devices())
+
+def run(ndev: int, sparse: bool) -> float:
+    """Median step seconds on a (ndev, 1) data mesh over the first
+    ``ndev`` devices."""
     cfg = get_smoke("bert-base-sten")
     mesh = make_host_mesh(ndev, 1)
     rules = ShardingRules(batch=("data",), embed=None, heads=None, ff=None,
                           vocab=None, expert=None)
     params = init_lm(jax.random.PRNGKey(0), cfg)
-    if {SPARSE}:
+    if sparse:
         sb = SparsityBuilder()
         sb.set_weight("*mlp.w*", ScalarFractionSparsifier(0.75),
                       FixedMaskTensor)
@@ -44,44 +47,32 @@ _WORKER = """
         cfg, AdamWConfig(lr=1e-3), steps_mod.StepConfig(remat="none"),
         mesh, rules)
     B = 2 * ndev   # fixed per-device batch (weak scaling)
-    batch = {{
+    batch = {
         "tokens": jax.random.randint(jax.random.PRNGKey(1), (B, 64), 0,
                                      cfg.vocab),
         "labels": jax.random.randint(jax.random.PRNGKey(2), (B, 64), 0,
                                      cfg.vocab),
-    }}
+    }
     with mesh:
         jstep = jax.jit(step)
-        out = jstep(params, opt, batch); jax.block_until_ready(out)
-        p, o, _ = out
+        p, o, _ = jax.block_until_ready(jstep(params, opt, batch))
         ts = []
         for _ in range(5):
             t0 = time.perf_counter()
             p, o, m = jstep(p, o, batch)
             jax.block_until_ready(m)
             ts.append(time.perf_counter() - t0)
-        ts.sort()
-    print("RESULT", ts[len(ts) // 2])
-"""
-
-
-def run(ndev: int, sparse: bool) -> float:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    code = textwrap.dedent(_WORKER).replace("{SPARSE}", str(sparse)) \
-        .replace("{{", "{").replace("}}", "}")
-    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, env=env, timeout=900)
-    assert out.returncode == 0, out.stderr[-2000:]
-    for line in out.stdout.splitlines():
-        if line.startswith("RESULT"):
-            return float(line.split()[1])
-    raise RuntimeError(out.stdout)
+    ts.sort()
+    return ts[len(ts) // 2]
 
 
 def main(quick=False):
-    devs = [1, 4] if quick else [1, 2, 4, 8]
+    have = len(jax.devices())
+    devs = [d for d in ([1, 4] if quick else [1, 2, 4, 8]) if d <= have]
+    if devs[-1] < 4:
+        raise RuntimeError(
+            f"weak_scaling needs at least 4 devices, found {have}; on a CPU "
+            f"host set XLA_FLAGS=--xla_force_host_platform_device_count=8")
     print("devices,dense_ms,sparse_ms,dense_eff,sparse_eff,sparse_overhead")
     base_d = base_s = None
     for nd in devs:

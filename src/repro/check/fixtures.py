@@ -174,7 +174,7 @@ def _r5_clean():
 # -- R6: VMEM overrun from a bad tuned tile ---------------------------------
 
 
-def _r6_program(name):
+def _r6_program(name, decode_m=4):
     from repro.models.common import mm
     w = _weight()
 
@@ -183,18 +183,19 @@ def _r6_program(name):
 
     return build_program(name, f, (_x(),), model_dtype=jnp.float32,
                          decode_path=True, sparse_weights={"w": w},
-                         decode_m=4)
+                         decode_m=decode_m)
 
 
 def _r6_trigger():
-    # a tuned (corrupt) tile so large the gathered-B block alone blows the
-    # budget; estimates bake at build time, while this table is active
+    # a tuned (corrupt) tile so large that, at a wide activation batch, the
+    # activation block alone blows the budget; estimates bake at build
+    # time, while this table is active
     bad = TuningTable(device=device_kind(),
                       entries={"gemv_pallas": {"tm": 1 << 20,
                                                "target_depth": 128}})
     set_active_table(bad)
     try:
-        return _r6_program("fixture/r6:trigger")
+        return _r6_program("fixture/r6:trigger", decode_m=1 << 16)
     finally:
         clear_active_table()
 

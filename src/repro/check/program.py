@@ -121,7 +121,10 @@ def _vmem_estimates(sparse_weights: dict, model_dtype, device_kind: str, *,
     while the active tuning table (if any) is the one the program traced
     against."""
     from repro.check.static_pass import gemv_vmem, spmm_vmem
+    from repro.launch.hlo_analysis import HW_BY_KIND
 
+    if device_kind not in HW_BY_KIND:
+        return []       # no budget to judge by: R7 reports the kind
     ests = []
     for path, w in sparse_weights.items():
         if not isinstance(w, GroupedNMTensor):

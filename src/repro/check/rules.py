@@ -1,4 +1,4 @@
-"""Rule registry: R1-R6 (plus R7, the device-model warning) as typed
+"""Rule registry: R1-R7 (R7: the device kind has no modelled peaks) as typed
 :class:`Rule` records binding an id, severity, description, and the
 detector functions from the jaxpr / HLO / trace-evidence passes.
 
@@ -90,8 +90,8 @@ register_rule(
     (static_pass.static_r6,),
 )
 register_rule(
-    "R7", "unmodelled-device", Severity.WARNING,
-    "The running device kind has no HW_BY_KIND entry; budgets and "
-    "roofline terms are modelled against TPU v5e constants.",
+    "R7", "unmodelled-device", Severity.ERROR,
+    "The running device kind has no HW_BY_KIND entry, so there are no "
+    "peaks or VMEM budget to judge it by (none is assumed).",
     (static_pass.static_r7,),
 )

@@ -1,18 +1,19 @@
 """Trace-evidence detectors (dispatch counters, conversion log, routed
 VMEM estimates, device-kind budgets): R1's counter half, R2, R6, R7.
 
-The VMEM estimators mirror the block shapes of ``kernels/nmg_gemv.py``
-and ``kernels/nmg_spmm.py`` exactly — per grid step, the operand tiles +
-output tile + scratch a routed ``(tm | tn, target_depth, stream)`` config
-makes resident — and compare them against the per-device budget in
-``launch/hlo_analysis.HW_BY_KIND``.  An oversized tuned tile is caught
-*here*, before a real-TPU run hits the Mosaic allocator.
+The VMEM estimators mirror the block shapes of ``kernels/nmg_spmm.py``
+(which the decode GEMV shares) — per grid step, the double-buffered
+blocks, accumulator and window temporaries a routed
+``(tm | tn, target_depth, stream)`` config makes resident — and compare
+them against the scoped VMEM limit in ``launch/hlo_analysis.HW_BY_KIND``.
+An oversized tuned tile is caught *here*, before it reaches the compiler.
+``tests/test_tpu_compile.py`` checks the shipped defaults against the
+compiler itself.
 """
 
 from __future__ import annotations
 
 import collections
-import math
 
 import jax.numpy as jnp
 
@@ -79,53 +80,58 @@ def _fmt_ctx(w, dtype) -> dict:
                 fmt=(w.n, w.m, w.g), gr=w.gr, dtype=jnp.dtype(dtype))
 
 
-def gemv_vmem(w, dtype, M: int, device_kind: str, *, weight: str = "") -> dict:
-    """Per-grid-step VMEM bytes of the routed decode GEMV config: index
-    slab + value tile + gathered-B tile (CG*m x M_pad) + output tile +
-    f32 accumulator scratch (the ``nmg_gemv_pallas`` block shapes)."""
-    ctx = _fmt_ctx(w, dtype)
-    cfg, src = routing.gemv_pallas_config(**ctx)
-    n, m, g = ctx["fmt"]
-    gr = ctx["gr"]
-    cg = math.comb(m, n) * g
-    tm = int(cfg["tm"])
-    m_pad = M + (-M) % tm
+def _step_vmem(w, dtype, M: int, tm: int, target_depth: int,
+               stream: bool) -> int:
+    """VMEM bytes of one grid step of ``kernels/nmg_spmm.nmg_pallas_call``:
+    double-buffered plan, value, activation and output blocks, the f32
+    accumulator, and the one-hot and decompressed-tile temporaries of one
+    window."""
+    from repro.kernels.nmg_spmm import (_groups_per_step, _sublanes,
+                                        windows)
+
+    n, m, g, gr = w.n, w.m, w.g, w.gr
+    R_pad, nblocks, _ = w.val.shape[-3:]   # stacked layers lead
+    nbn, k_pad, G = nblocks * n, nblocks * m, R_pad // gr
     vb = jnp.dtype(dtype).itemsize
-    nbytes = (cg * 4                      # SMEM pattern indices
-              + gr * cg * n * vb          # value tile
-              + cg * m * m_pad * vb       # gathered B tile
-              + gr * m_pad * vb           # output tile
-              + gr * m_pad * 4)           # f32 accumulator scratch
-    hw, _ = hw_for_device(device_kind)
+    sub = _sublanes(dtype)
+    TM = min(-(-M // sub) * sub, max(sub, tm // sub * sub))
+    tg = _groups_per_step(G, gr, _sublanes(w.val.dtype))
+    wins = windows(n, m, g, nbn, target_depth)
+    tc = max(c1 - c0 for c0, c1, _, _ in wins)
+    tk = max(k1 - k0 for _, _, k0, k1 in wins)
+    step_tc, step_tk = (tc, tk) if not stream else (nbn, k_pad)
+    lanes = lambda x: -(-x // 128) * 128          # noqa: E731
+    blocks = (tg * 8 * lanes(step_tc) * 4                     # plan
+              + tg * gr * lanes(step_tc) * vb                 # values
+              + TM * lanes(step_tk) * vb                      # activations
+              + tg * TM * lanes(gr) * 4)                      # output
+    temps = (tk * lanes(tc) * (4 + vb)                        # one-hot
+             + gr * lanes(tk) * (4 + vb)                      # dense tile
+             + tg * TM * lanes(gr) * 4)                       # accumulator
+    return 2 * blocks + temps
+
+
+def gemv_vmem(w, dtype, M: int, device_kind: str, *, weight: str = "") -> dict:
+    """VMEM bytes of one grid step of the routed decode GEMV config."""
+    cfg, src = routing.gemv_pallas_config(**_fmt_ctx(w, dtype))
+    nbytes = _step_vmem(w, dtype, M, int(cfg["tm"]),
+                        int(cfg["target_depth"]), stream=True)
     return {"kernel": "nmg_gemv", "weight": weight, "config": dict(cfg),
             "source": src, "M": int(M), "bytes": int(nbytes),
-            "budget": int(hw["vmem_bytes"]), "device": device_kind}
+            "budget": int(hw_for_device(device_kind)["vmem_bytes"]),
+            "device": device_kind}
 
 
 def spmm_vmem(w, dtype, N: int, device_kind: str, *, weight: str = "") -> dict:
-    """Per-grid-step VMEM bytes of the routed prefill SpMM config.  The
-    streamed schedule keeps a full K_pad x tn B slab resident plus the
-    double-buffered value scratch; the grid schedule tiles B per chunk."""
-    ctx = _fmt_ctx(w, dtype)
-    cfg, src = routing.spmm_pallas_config(**ctx)
-    n, m, g = ctx["fmt"]
-    gr = ctx["gr"]
-    cg = math.comb(m, n) * g
-    tn = min(int(cfg["tn"]), N + (-N) % 128)
-    k_pad = ctx["K"] + (-ctx["K"]) % (m * g)
-    vb = jnp.dtype(dtype).itemsize
-    if cfg.get("stream", True):
-        nbytes = (k_pad * tn * vb           # resident B slab
-                  + 2 * gr * cg * n * vb    # double-buffered value scratch
-                  + gr * tn * 4)            # f32 output tile
-    else:
-        nbytes = (cg * m * tn * vb          # per-chunk B tile
-                  + gr * cg * n * vb        # value tile
-                  + gr * tn * 4)
-    hw, _ = hw_for_device(device_kind)
+    """VMEM bytes of one grid step of the routed prefill SpMM config."""
+    cfg, src = routing.spmm_pallas_config(**_fmt_ctx(w, dtype))
+    nbytes = _step_vmem(w, dtype, N, int(cfg["tn"]),
+                        int(cfg["target_depth"]),
+                        stream=bool(cfg.get("stream", True)))
     return {"kernel": "nmg_spmm", "weight": weight, "config": dict(cfg),
             "source": src, "N": int(N), "bytes": int(nbytes),
-            "budget": int(hw["vmem_bytes"]), "device": device_kind}
+            "budget": int(hw_for_device(device_kind)["vmem_bytes"]),
+            "device": device_kind}
 
 
 def static_r6(program) -> list:
@@ -150,17 +156,16 @@ def static_r6(program) -> list:
 
 
 def static_r7(program) -> list:
-    """Device kind with no modelled HW entry: roofline terms and VMEM
-    budgets silently fall back to the TPU v5e numbers (warning — the run
-    still works, the *model* is what's off)."""
-    _, matched = hw_for_device(program.device_kind)
-    if matched:
-        return []
-    return [Diagnostic(
-        rule="R7", severity=Severity.WARNING, entry=program.name,
-        message=f"device kind {program.device_kind!r} has no entry in "
-                f"HW_BY_KIND — VMEM budgets and roofline terms are "
-                f"modelled against the TPU v5e constants",
-        op=program.device_kind, location="hw-model",
-        fix="add this device kind to launch/hlo_analysis.HW_BY_KIND",
-    )]
+    """Device kind with no HW entry: there are no peaks or VMEM budget to
+    judge it by, and none is assumed (``hw_for_device`` raises)."""
+    try:
+        hw_for_device(program.device_kind)
+    except KeyError as e:
+        return [Diagnostic(
+            rule="R7", severity=Severity.ERROR, entry=program.name,
+            message=str(e.args[0]), op=program.device_kind,
+            location="hw-model",
+            fix="add this device kind's published peaks and its scoped "
+                "VMEM limit to launch/hlo_analysis.HW_BY_KIND",
+        )]
+    return []

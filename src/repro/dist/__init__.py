@@ -6,7 +6,6 @@ Submodules:
   * ``collectives`` — densify-allreduce-resparsify + value-only fast path
   * ``compression`` — top-k + error-feedback gradient exchange
   * ``elastic``     — straggler watchdog and remesh planning
-  * ``compat``      — version-portable ``shard_map``
 """
 
 from repro.dist.collectives import (

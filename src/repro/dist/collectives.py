@@ -24,7 +24,6 @@ import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.layouts import FixedMaskTensor
-from repro.dist.compat import shard_map
 
 __all__ = [
     "allreduce_mean",
@@ -40,7 +39,7 @@ def allreduce_mean(x, mesh: Mesh, axis: str):
     the body runs per-device and ``pmean``s over ``axis``.
     """
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(P(),), out_specs=P(),
+        jax.shard_map, mesh=mesh, in_specs=(P(),), out_specs=P(),
         check_vma=False,
     )
     def _mean(v):

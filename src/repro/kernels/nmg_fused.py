@@ -1,52 +1,48 @@
 """Decode megakernels: fused QKV and fused gated-FFN Pallas launches.
 
-PR 4's decode kernel (:mod:`repro.kernels.nmg_gemv`) wins the serving
-regime but still launches once *per projection* and re-gathers each fiber
-group's activations per launch.  The paper's argument (and the Hoefler et
-al. survey's) is that grouped n:m only pays when the gather cost is
-amortized across the whole operator — so the decode step wants one
-weight-stationary launch per fused operator, not one per weight.
+The decode kernel (:mod:`repro.kernels.nmg_gemv`) launches once *per
+projection*.  The paper's argument (and the Hoefler et al. survey's) is
+that grouped n:m only pays when the per-call overheads are amortized
+across the whole operator — so the decode step wants one launch per fused
+operator, not one per weight.
 
-Two fusions, both exploiting n:m:g storage invariants:
+Two fusions, both exploiting n:m:g storage invariants, both launches of
+the shared kernel in :mod:`repro.kernels.nmg_spmm`:
 
 * **QKV** (:func:`nmg_qkv_pallas`): ``wq``/``wk``/``wv`` share the
   contraction axis (d_model) and, when sparsified together, the
   (n, m, g, gr) format.  Their compressed storage concatenates along the
-  canonical output-row axis — ``val`` on rows, ``blk_idx`` on fiber
+  canonical output-row axis — ``val`` on rows, the gather plan on fiber
   groups, legal because conversion pads every operand's rows to a ``gr``
-  multiple — so **one** ``gemv_pallas_call`` launch computes all three
-  projections, gathering each fiber group's activation rows once per
-  token.  Per-row contractions are independent and run the identical
-  per-chunk accumulation order as three separate launches, so fused and
-  sequential outputs agree **bitwise** (pinned by tests/test_megakernel).
+  multiple — so **one** launch computes all three projections.  Every
+  fiber group runs the same window dots as in three separate launches,
+  so fused and sequential outputs agree **bitwise** (pinned by
+  tests/test_megakernel).
 * **Gated FFN** (:func:`nmg_ffn_pallas`): the gated-MLP packs ``w1`` and
   ``gate`` into one ``[D, 2F]`` weight; the fusion is the in-kernel gate
-  epilogue.  The grid walks F/gr output stripes with *two* f32
-  accumulators per step — the ``u`` stripe (rows [f, f+gr)) and its
-  ``v`` partner at row offset +F — and the last chunk step casts both to
-  the activation dtype and emits ``act(u) * v`` directly, exactly the op
-  order ``models/transformer._sublayer_ffn`` runs after a sequential
-  projection (split -> act -> multiply).  silu is bitwise-stable (the
-  logistic lowers to one primitive); approximate-gelu's tanh polynomial
-  may differ by ulps depending on what XLA fuses it with.
+  epilogue.  Each output group carries *two* f32 accumulators — the ``u``
+  group and its ``v`` partner at row offset +F — and the last step casts
+  both to the activation dtype and emits ``act(u) * v`` directly, exactly
+  the op order ``models/transformer._sublayer_ffn`` runs after a
+  sequential projection (split -> act -> multiply).  silu is
+  bitwise-stable (the logistic lowers to one primitive); approximate-
+  gelu's tanh polynomial may differ by ulps depending on what XLA fuses
+  it with.
 
-Both kernels keep the gemv contract: f32 VMEM scratch accumulation, one
-dtype cast in the epilogue, M padded to the lane width.
+Both keep the gemv contract: f32 accumulation, one dtype cast in the
+epilogue.
 """
 
 from __future__ import annotations
 
 import functools
-import math
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.layouts import GroupedNMTensor, nm_patterns
-from repro.kernels.nmg_gemv import gemv_pallas_call
+from repro.core.layouts import GroupedNMTensor
+from repro.kernels.nmg_spmm import nmg_pallas_call, pad_k, storage_views
 
 __all__ = [
     "act_fn",
@@ -61,9 +57,11 @@ __all__ = [
 def act_fn(name: str):
     """The model stack's activation by name (gelu is the tanh approximation
     ``models/transformer._act`` uses — the fused epilogue must match it
-    bitwise)."""
+    bitwise).  silu is spelled out as ``x / (1 + exp(-x))`` op by op: XLA
+    expands ``jax.nn.silu``'s logistic into exactly these ops (bitwise
+    equal in f32 and bf16), and Mosaic cannot lower a bf16 logistic."""
     if name == "silu":
-        return jax.nn.silu
+        return lambda x: x * (1 / (1 + jnp.exp(-x)))
     return functools.partial(jax.nn.gelu, approximate=True)
 
 
@@ -117,61 +115,24 @@ def fused_segments(ws: Sequence) -> list:
     return segs
 
 
+@functools.partial(
+    jax.jit, static_argnames=("out_dtype", "tm", "interpret", "target_depth")
+)
 def nmg_qkv_pallas(ws: Sequence, b: jnp.ndarray, *, out_dtype=None,
                    tm: int = 128, interpret: bool = True,
                    target_depth: int = 128) -> tuple:
     """All projections of ``ws`` against one decode-shaped ``b`` [K, M] in
-    a single weight-stationary launch.  Returns one [R_i, M] array per
-    projection, in ``out_dtype`` (default f32)."""
+    a single launch.  Returns one [R_i, M] array per projection, in
+    ``out_dtype`` (default f32)."""
     assert fusable_qkv(ws), "operands not fusable; route per-projection"
     w0 = ws[0]
-    val = jnp.concatenate([w.val for w in ws], axis=0)
-    blk_idx = jnp.concatenate([w.blk_idx for w in ws], axis=0)
-    out = gemv_pallas_call(val, blk_idx, b, n=w0.n, m=w0.m, g=w0.g,
-                           gr=w0.gr, out_dtype=out_dtype, tm=tm,
-                           interpret=interpret, target_depth=target_depth)
-    return tuple(out[off:off + R] for off, R in fused_segments(ws))
-
-
-def _ffn_kernel(idx_u_ref, idx_v_ref, val_u_ref, val_v_ref, b_ref, o_ref,
-                acc_u_ref, acc_v_ref, *, n, m, g, gr, CG, pats, nchunks,
-                batch_positions, act):
-    ki = pl.program_id(1)
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_u_ref[...] = jnp.zeros_like(acc_u_ref)
-        acc_v_ref[...] = jnp.zeros_like(acc_v_ref)
-
-    # same inner loop as the gemv kernel, run for the stripe's u rows and
-    # its gate partner at +F — one B chunk-slab feeds both contractions
-    for idx_ref, val_ref, acc_ref in (
-        (idx_u_ref, val_u_ref, acc_u_ref),
-        (idx_v_ref, val_v_ref, acc_v_ref),
-    ):
-        vals = val_ref[...].reshape(gr, CG * n)
-        for start in range(0, CG, batch_positions):
-            stop = min(start + batch_positions, CG)
-            rows = []
-            for p in range(start, stop):  # static unroll; pattern p//g static
-                b_loc = idx_ref[0, 0, p] - ki * CG
-                mrows = b_ref[pl.ds(b_loc * m, m), :]
-                rows.extend(mrows[l : l + 1, :] for l in pats[p // g])
-            gathered = jnp.concatenate(rows, axis=0)
-            acc_ref[...] += jnp.dot(
-                vals[:, start * n : stop * n],
-                gathered.astype(vals.dtype),
-                preferred_element_type=jnp.float32,
-            )
-
-    @pl.when(ki == nchunks - 1)
-    def _epilogue():
-        # cast first, gate second — the exact op order the sequential path
-        # runs (projection epilogue cast, then split/act/multiply), so the
-        # fused output is bitwise-identical to it
-        u = acc_u_ref[...].astype(o_ref.dtype)
-        v = acc_v_ref[...].astype(o_ref.dtype)
-        o_ref[...] = act_fn(act)(u) * v
+    val2 = jnp.concatenate([storage_views(w)[0] for w in ws], axis=0)
+    cols3 = jnp.concatenate([storage_views(w)[1] for w in ws], axis=0)
+    out = nmg_pallas_call(
+        val2, cols3, pad_k(w0, b.T), n=w0.n, m=w0.m, g=w0.g, gr=w0.gr,
+        out_dtype=jnp.float32 if out_dtype is None else out_dtype, tm=tm,
+        target_depth=target_depth, stream=True, interpret=interpret)
+    return tuple(out[:, off:off + R].T for off, R in fused_segments(ws))
 
 
 @functools.partial(
@@ -183,50 +144,13 @@ def nmg_ffn_pallas(w: GroupedNMTensor, b: jnp.ndarray, *, act: str = "silu",
                    target_depth: int = 128) -> jnp.ndarray:
     """Gated-MLP pair in one launch: ``w`` is the packed [D, 2F] weight
     (sparse_dim=0), ``b`` [D, M] the decode activations.  Returns
-    ``act(u) @ gate`` = [F, M] in ``out_dtype`` (default f32)."""
-    n, m, g, gr = w.n, w.m, w.g, w.gr
-    C = math.comb(m, n)
-    CG = C * g
-    pats = [tuple(int(v) for v in row) for row in nm_patterns(n, m)]
-    out_dtype = jnp.dtype(out_dtype) if out_dtype is not None else jnp.float32
-
-    val, blk_idx = w.val, w.blk_idx
-    R_pad, nblocks, _ = val.shape
-    Gr, nchunks, _ = blk_idx.shape
+    ``act(u) * v`` = [F, M] in ``out_dtype`` (default f32)."""
     F = _canon_R(w) // 2
     assert fusable_ffn(w, F), "weight not fusable; route per-projection"
-    half = Gr // 2
-    K_pad = nblocks * m
-
-    K, M = b.shape
-    m_pad = min(tm, max(8, M)) if interpret else tm
-    b_p = jnp.pad(b, ((0, K_pad - K), (0, (-M) % m_pad)))
-    M_pad = b_p.shape[1]
-
-    batch_positions = max(1, target_depth // n)
-    grid = (half, nchunks)
-
-    out = pl.pallas_call(
-        functools.partial(
-            _ffn_kernel, n=n, m=m, g=g, gr=gr, CG=CG, pats=pats,
-            nchunks=nchunks, batch_positions=batch_positions, act=act,
-        ),
-        grid=grid,
-        in_specs=[
-            # the stripe's index row and its gate partner at group +half:
-            # the same array twice under shifted index maps
-            pl.BlockSpec((1, 1, CG), lambda gi, ki: (gi, ki, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, CG), lambda gi, ki: (gi + half, ki, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((gr, CG, n), lambda gi, ki: (gi, ki, 0)),
-            pl.BlockSpec((gr, CG, n), lambda gi, ki: (gi + half, ki, 0)),
-            pl.BlockSpec((CG * m, M_pad), lambda gi, ki: (ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((gr, M_pad), lambda gi, ki: (gi, 0)),
-        out_shape=jax.ShapeDtypeStruct((F, M_pad), out_dtype),
-        scratch_shapes=[pltpu.VMEM((gr, M_pad), jnp.float32),
-                        pltpu.VMEM((gr, M_pad), jnp.float32)],
-        interpret=interpret,
-    )(blk_idx, blk_idx, val, val, b_p)
-    return out[:, :M]
+    val2, cols3 = storage_views(w)
+    out = nmg_pallas_call(
+        val2, cols3, pad_k(w, b.T), n=w.n, m=w.m, g=w.g, gr=w.gr,
+        out_dtype=jnp.float32 if out_dtype is None else out_dtype, tm=tm,
+        target_depth=target_depth, stream=True, interpret=interpret,
+        gate_act=act_fn(act))
+    return out.T

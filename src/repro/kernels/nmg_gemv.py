@@ -3,132 +3,32 @@
 Computes ``C[R, M] = A @ B`` where A is the canonical [R, K(sparse)] view of
 a :class:`GroupedNMTensor` and B is a *narrow* dense right operand
 [K, M <= ~16] — the shape a serving decode step produces (B = the batch of
-per-slot activations, transposed).  The wide-N SpMM kernel
-(:mod:`repro.kernels.nmg_spmm`) tiles B columns for prefill-shaped operands;
-in the decode regime that tiling degenerates (one mostly-padding column
-tile), so this kernel is specialized the other way around:
+per-slot activations, transposed).
 
-* **weight-stationary, output-tiled**: the grid is ``(R_pad/gr, nchunks)``
-  — each step owns a ``gr``-row output stripe and walks the chunk (K)
-  dimension innermost; the compressed value tile is the large resident
-  operand and the whole (padded) B chunk-slab rides along in VMEM, which is
-  affordable precisely because M is tiny.
-* **f32 accumulator scratch + dtype-preserving epilogue**: partial products
-  accumulate in an f32 VMEM scratch across chunk steps; the *last* chunk
-  step casts once into the output ref, which carries the caller-requested
-  dtype.  The serving path asks for the activation dtype, eliminating the
-  f32 round-trip (and the separate ``astype`` copy) the SpMM contract
-  forces on ``nmg_linear``.
-* The gather strategy is the same dynamic-base/static-offset row slicing as
-  the SpMM kernel: chunk position p carries pattern ``p // g`` at compile
-  time, so only the m-block base index (SMEM) is runtime data.
+On the TPU this is the kernel of :mod:`repro.kernels.nmg_spmm` with the
+decode regime's settings:
 
-M is padded to the TPU lane width (``tm``); interpret mode (CPU tests)
-accepts any padding.
+* the activations ride on the sublane axis, so a decode batch pads to one
+  8-row (f32) or 16-row (bf16) tile instead of a 128-lane one, and the
+  whole ``[M, K]`` slab stays resident while the compressed weights stream
+  through — the weight bytes, read compressed, are what a decode step
+  pays for;
+* f32 accumulation with a dtype-preserving epilogue: the last step casts
+  once into the caller-requested dtype.  The serving path asks for the
+  activation dtype, so no f32 copy of the output is written.
 """
 
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.layouts import GroupedNMTensor, nm_patterns
+from repro.core.layouts import GroupedNMTensor
+from repro.kernels.nmg_spmm import nmg_rows
 
-__all__ = ["nmg_gemv_pallas", "gemv_pallas_call"]
-
-
-def _kernel(idx_ref, val_ref, b_ref, o_ref, acc_ref, *, n, m, g, gr, CG,
-            pats, nchunks, batch_positions):
-    ki = pl.program_id(1)
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    vals = val_ref[...].reshape(gr, CG * n)  # contiguous compressed tile
-
-    # pack gathered B rows into ~128-deep contractions (MXU-friendly even
-    # though the N side is a single narrow tile)
-    for start in range(0, CG, batch_positions):
-        stop = min(start + batch_positions, CG)
-        rows = []
-        for p in range(start, stop):  # static unroll; pattern p//g static
-            b_loc = idx_ref[0, 0, p] - ki * CG  # dynamic m-block base
-            mrows = b_ref[pl.ds(b_loc * m, m), :]  # one dynamic row-slice
-            rows.extend(mrows[l : l + 1, :] for l in pats[p // g])
-        gathered = jnp.concatenate(rows, axis=0)  # ((stop-start)*n, TM)
-        acc_ref[...] += jnp.dot(
-            vals[:, start * n : stop * n],
-            gathered.astype(vals.dtype),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(ki == nchunks - 1)
-    def _epilogue():
-        # single cast into the caller-requested output dtype
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("n", "m", "g", "gr", "out_dtype", "tm", "interpret",
-                     "target_depth"),
-)
-def gemv_pallas_call(val: jnp.ndarray, blk_idx: jnp.ndarray, b: jnp.ndarray,
-                     *, n: int, m: int, g: int, gr: int, out_dtype=None,
-                     tm: int = 128, interpret: bool = True,
-                     target_depth: int = 128) -> jnp.ndarray:
-    """The raw decode-kernel launch on the storage arrays: one
-    ``pallas_call`` over (``val`` [R_pad, nblocks, n], ``blk_idx``
-    [R_pad/gr, nchunks, C*g], ``b`` [K, M]) returning the *uncropped*
-    [R_pad, M] product.
-
-    Factored out of :func:`nmg_gemv_pallas` so the fused megakernels
-    (:mod:`repro.kernels.nmg_fused`) can launch the identical kernel body
-    over row-concatenated operands: every output row's contraction is
-    independent and runs the same per-chunk accumulation order, so fused
-    and per-projection launches agree bitwise by construction."""
-    C = math.comb(m, n)
-    CG = C * g
-    pats = [tuple(int(v) for v in row) for row in nm_patterns(n, m)]
-    out_dtype = jnp.dtype(out_dtype) if out_dtype is not None else jnp.float32
-
-    R_pad, nblocks, _ = val.shape
-    Gr, nchunks, _ = blk_idx.shape
-    K_pad = nblocks * m
-
-    # pad B to the compressed K extent and the lane width in M
-    K, M = b.shape
-    m_pad = min(tm, max(8, M)) if interpret else tm
-    b_p = jnp.pad(b, ((0, K_pad - K), (0, (-M) % m_pad)))
-    M_pad = b_p.shape[1]
-
-    batch_positions = max(1, target_depth // n)
-    grid = (Gr, nchunks)
-
-    out = pl.pallas_call(
-        functools.partial(
-            _kernel, n=n, m=m, g=g, gr=gr, CG=CG, pats=pats,
-            nchunks=nchunks, batch_positions=batch_positions,
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, CG), lambda gi, ki: (gi, ki, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((gr, CG, n), lambda gi, ki: (gi, ki, 0)),
-            pl.BlockSpec((CG * m, M_pad), lambda gi, ki: (ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((gr, M_pad), lambda gi, ki: (gi, 0)),
-        out_shape=jax.ShapeDtypeStruct((R_pad, M_pad), out_dtype),
-        scratch_shapes=[pltpu.VMEM((gr, M_pad), jnp.float32)],
-        interpret=interpret,
-    )(blk_idx, val, b_p)
-    return out[:, :M]
+__all__ = ["nmg_gemv_pallas"]
 
 
 @functools.partial(
@@ -138,11 +38,9 @@ def nmg_gemv_pallas(a: GroupedNMTensor, b: jnp.ndarray, *,
                     out_dtype=None, tm: int = 128, interpret: bool = True,
                     target_depth: int = 128) -> jnp.ndarray:
     """C = A_canonical @ B via the decode kernel.  Returns [R, M] in
-    ``out_dtype`` (default: f32, matching the SpMM contract)."""
-    out = gemv_pallas_call(a.val, a.blk_idx, b, n=a.n, m=a.m, g=a.g,
-                           gr=a.gr, out_dtype=out_dtype, tm=tm,
-                           interpret=interpret, target_depth=target_depth)
-    # crop row padding (canonical row count)
-    sd = a.sparse_dim % 2
-    R = a.dense_shape[1 - sd]
-    return out[:R]
+    ``out_dtype`` (default: f32, matching the SpMM contract).  ``tm`` caps
+    the activation rows per grid step."""
+    out_dtype = jnp.float32 if out_dtype is None else out_dtype
+    return nmg_rows(a, b.T, out_dtype=out_dtype, tm=tm,
+                    target_depth=target_depth, stream=True,
+                    interpret=interpret).T

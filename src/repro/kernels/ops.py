@@ -10,9 +10,9 @@ kernel choice depends on format *and* operand shape):
   right operand        path                       regime
   -----------------    ------------------------   -------------------------
   M <= decode_m_max    ``nmg_gemv``  (decode)     serving decode GEMV: tiny
-                                                  activation batch, weight-
-                                                  stationary, dtype epilogue
-  M >  decode_m_max    ``nmg_spmm``  (prefill)    wide right operand, column
+                                                  activation batch, dtype
+                                                  epilogue
+  M >  decode_m_max    ``nmg_spmm``  (prefill)    wide right operand, token
                                                   tiled, f32 accumulator out
 
 The routing decisions — the gemv/spmm crossover ``decode_m_max``, the
@@ -21,7 +21,8 @@ spmm gathered-block cap, and the Pallas gemv tile config — come from
 :class:`~repro.tune.table.TuningTable` (device kind + shape bucket) with
 shipped defaults (``DECODE_M_MAX``, ``_SPMM_BLOCK_ELEMS`` below) that
 reproduce the historical hard-coded heuristics exactly when no table is
-loaded.  A table changes only *which* path runs, never its output.
+loaded.  A table changes only *which* path runs, never its output — save
+a Pallas window depth (``target_depth``), which reassociates the f32 sum.
 Lookups happen at trace time, so load tables before compiling consumers
 (the serving warmup hook does this in the right order).
 
@@ -243,8 +244,9 @@ def nmg_gemv_xla(a: GroupedNMTensor, b: jnp.ndarray, *, out_dtype=None,
                  transpose_out: bool = False) -> jnp.ndarray:
     """Activation-stationary XLA decode path: B is small enough to gather
     in one shot, so the whole product is a single gather + einsum over the
-    precomputed plan.  ``transpose_out=True`` emits [M, R] directly (the
-    orientation ``nmg_linear`` wants), skipping the output transpose."""
+    precomputed plan — the contraction :func:`nmg_spmm_xla` runs, so the
+    two routes agree bitwise.  ``transpose_out=True`` returns [M, R] (the
+    orientation ``nmg_linear`` wants)."""
     gr = a.gr
     val = a.val
     R_pad, nblocks, n = val.shape
@@ -259,15 +261,12 @@ def nmg_gemv_xla(a: GroupedNMTensor, b: jnp.ndarray, *, out_dtype=None,
     val_g = val.reshape(Gr, gr, nblocks * n)
     sd = a.sparse_dim % 2
     R = a.dense_shape[1 - sd]
-    spec = "grk,gkm->mgr" if transpose_out else "grk,gkm->grm"
-    out = jnp.einsum(spec, val_g.astype(jnp.float32), xg.astype(jnp.float32))
-    if transpose_out:
-        out = out.reshape(M, R_pad)[:, :R]
-    else:
-        out = out.reshape(R_pad, M)[:R]
+    out = jnp.einsum("grk,gkm->grm", val_g.astype(jnp.float32),
+                     xg.astype(jnp.float32))
+    out = out.reshape(R_pad, M)[:R]
     if out_dtype is not None:
         out = out.astype(out_dtype)
-    return out
+    return out.T if transpose_out else out
 
 
 # ---------------------------------------------------------------------------
@@ -306,15 +305,13 @@ def nmg_qkv_xla(ws, b: jnp.ndarray, *, out_dtype=None,
     xg = jnp.take(b_p, cols.reshape(-1), axis=0)
     xg = xg.reshape(Gr, nblocks * n, M)
     val_g = val.reshape(Gr, gr, nblocks * n)
-    spec = "grk,gkm->mgr" if transpose_out else "grk,gkm->grm"
-    out = jnp.einsum(spec, val_g.astype(jnp.float32), xg.astype(jnp.float32))
-    out = out.reshape(M, R_pad) if transpose_out else out.reshape(R_pad, M)
+    out = jnp.einsum("grk,gkm->grm", val_g.astype(jnp.float32),
+                     xg.astype(jnp.float32))
+    out = out.reshape(R_pad, M)
     if out_dtype is not None:
         out = out.astype(out_dtype)
-    segs = fused_segments(ws)
-    if transpose_out:
-        return tuple(out[:, off:off + R] for off, R in segs)
-    return tuple(out[off:off + R] for off, R in segs)
+    outs = tuple(out[off:off + R] for off, R in fused_segments(ws))
+    return tuple(o.T for o in outs) if transpose_out else outs
 
 
 def nmg_qkv(ws, b: jnp.ndarray, *, out_dtype=None,

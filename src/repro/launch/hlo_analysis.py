@@ -36,38 +36,48 @@ __all__ = ["collective_bytes", "analyze_hlo", "roofline_terms", "HW",
            "HW_BY_KIND", "DEFAULT_HW_KIND", "hw_for_device",
            "parse_module", "inst_operands"]
 
-#: per-chip constants keyed by ``tune.table.device_kind()`` spelling —
-#: TPU v5e numbers are assignment-provided; the cpu entry is a rough
-#: host-class model so CI runs don't trip the unmodelled-device warning
+#: per-chip constants keyed by the ``tune.table.device_kind()`` spelling of
+#: what the device reports (a TPU v5e reports ``device_kind`` "TPU v5
+#: lite").  v5e peaks: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s
+#: bf16, 819 GB/s HBM, 16 GB HBM, 1,600 Gbit/s ICI (4 links x 50 GB/s).
+#: ``vmem_bytes`` is the scoped VMEM limit the TPU compiler enforces on a
+#: kernel's pipelined blocks and temporaries ("Scoped allocation ... limit
+#: 16.00M"), not the 128 MiB of physical VMEM.  The cpu entry exists so the
+#: checker can run on CPU; its ``vmem_bytes`` models the v5e limit
+#: (interpret mode runs the v5e kernels) and its rates are nominal.
 HW_BY_KIND = {
-    "tpu:tpu_v5e": {
+    "tpu:tpu_v5_lite": {
         "peak_flops_bf16": 197e12,   # FLOP/s
         "hbm_bw": 819e9,             # B/s
+        "hbm_bytes": 16e9,           # B
         "ici_bw": 50e9,              # B/s per link
-        "vmem_bytes": 128 * 2**20,   # per-core VMEM budget
+        "vmem_bytes": 16 * 2**20,    # scoped VMEM limit per kernel
     },
     "cpu:cpu": {
         "peak_flops_bf16": 2e12,
         "hbm_bw": 100e9,
+        "hbm_bytes": 16e9,
         "ici_bw": 50e9,
-        "vmem_bytes": 128 * 2**20,   # interpret mode models the v5e budget
+        "vmem_bytes": 16 * 2**20,
     },
 }
 
-DEFAULT_HW_KIND = "tpu:tpu_v5e"
+#: the chip this repository targets; roofline modelling of a compile-only
+#: dry-run (no device attached) uses it explicitly
+DEFAULT_HW_KIND = "tpu:tpu_v5_lite"
 
-#: the historical module-level constant — still the v5e entry, so every
-#: existing roofline/benchmark import keeps its exact numbers
 HW = HW_BY_KIND[DEFAULT_HW_KIND]
 
 
-def hw_for_device(kind: str | None = None):
-    """-> (hw constants dict, matched: bool).  Unknown/None kinds fall
-    back to the TPU v5e entry with ``matched=False`` — the checker turns
-    that into the R7 warning rather than guessing numbers."""
-    if kind in HW_BY_KIND:
-        return HW_BY_KIND[kind], True
-    return HW_BY_KIND[DEFAULT_HW_KIND], False
+def hw_for_device(kind: str) -> dict:
+    """The constants for ``kind``; a kind with no entry is an error (no
+    peak is assumed for a device nobody measured)."""
+    if kind not in HW_BY_KIND:
+        raise KeyError(
+            f"device kind {kind!r} has no entry in HW_BY_KIND (known: "
+            f"{', '.join(sorted(HW_BY_KIND))}); add its published peaks "
+            f"to launch/hlo_analysis.py")
+    return HW_BY_KIND[kind]
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -475,7 +485,7 @@ class RooflineTerms:
 def roofline_terms(flops_per_dev: float, bytes_per_dev: float,
                    coll_bytes_per_dev: float,
                    device_kind: str | None = None) -> RooflineTerms:
-    hw = HW if device_kind is None else hw_for_device(device_kind)[0]
+    hw = HW if device_kind is None else hw_for_device(device_kind)
     return RooflineTerms(
         compute_s=flops_per_dev / hw["peak_flops_bf16"],
         memory_s=bytes_per_dev / hw["hbm_bw"],

@@ -124,17 +124,13 @@ def main():
                     help="BENCH_bench.json to render the kernel-roofline "
                          "section from (fig6 megakernel records)")
     ap.add_argument("--device-kind", default=DEFAULT_HW_KIND,
+                    choices=sorted(HW_BY_KIND),
                     help="HW constants to model against (keys of "
-                         f"launch.hlo_analysis.HW_BY_KIND: "
-                         f"{', '.join(sorted(HW_BY_KIND))})")
+                         "launch.hlo_analysis.HW_BY_KIND)")
     args = ap.parse_args()
     recs = load(args.dir, args.mesh, args.tag)
-    hw, matched = hw_for_device(args.device_kind)
-    kind = args.device_kind if matched else DEFAULT_HW_KIND
-    if not matched:
-        print(f"warning: device kind {args.device_kind!r} has no "
-              f"HW_BY_KIND entry — modelling against {DEFAULT_HW_KIND} "
-              f"(the repro.check R7 diagnostic flags this too)")
+    kind = args.device_kind
+    hw = hw_for_device(kind)
     print(f"hardware ({kind}): {hw['peak_flops_bf16']/1e12:.0f} TF/s bf16, "
           f"{hw['hbm_bw']/1e9:.0f} GB/s HBM, {hw['ici_bw']/1e9:.0f} GB/s ICI"
           " per chip\n")

@@ -265,8 +265,10 @@ def main(argv=None):
         # default routing while reporting a "tuned" run
         ap.error("--tune requires the warmup pass; drop --no-warmup")
 
+    from repro.compile_cache import enable_compile_cache
     from repro.tune import load_table_cli
 
+    enable_compile_cache()
     load_table_cli(args.tuning_table)  # --tuning-table or $REPRO_TUNE_TABLE
 
     if args.check:

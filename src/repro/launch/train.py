@@ -55,8 +55,8 @@ from repro.optim import (
 )
 from repro.optim.sparse_update import resparsify_params
 
-__all__ = ["build_sparse_params", "retarget_sparsity", "make_train_step",
-           "make_multi_step", "stack_batches", "main"]
+__all__ = ["build_sparse_params", "retarget_sparsity", "gmp_schedule",
+           "make_train_step", "make_multi_step", "stack_batches", "main"]
 
 
 def build_sparse_params(params, sparsity: float, targets=("mlp", "attn.wo")):
@@ -78,6 +78,22 @@ def retarget_sparsity(params, sparsity: float):
     aux is preserved, keeping treedefs synced with optimizer moments)."""
     return resparsify_params(params, recompute_pattern=True,
                              target_sparsity=float(sparsity))
+
+
+def gmp_schedule(mode, sparsity: float, steps: int,
+                 num_layers: int) -> GMPSchedule:
+    """The schedule ``--gmp MODE --sparsity S --steps N`` trains under:
+    one-shot by default, ramps from N/10 to 0.8 N, recomputing the pattern
+    20 times along the way."""
+    mode = mode or "one_shot"
+    return GMPSchedule(
+        mode=mode,
+        target_sparsity=sparsity or 0.5,
+        begin_step=0 if mode == "one_shot" else steps // 10,
+        end_step=int(steps * 0.8),
+        recompute_every=max(1, steps // 20),
+        num_layers=num_layers,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -210,8 +226,10 @@ def main(argv=None):
     args.log_every = max(1, args.log_every)
     args.ckpt_every = max(1, args.ckpt_every)
 
+    from repro.compile_cache import enable_compile_cache
     from repro.tune import load_table_cli
 
+    enable_compile_cache()
     load_table_cli(args.tuning_table)  # --tuning-table or $REPRO_TUNE_TABLE
 
     if args.check:
@@ -230,15 +248,7 @@ def main(argv=None):
 
     gmp = None
     if args.gmp or args.sparsity > 0:
-        gmp = GMPSchedule(
-            mode=args.gmp or "one_shot",
-            target_sparsity=args.sparsity or 0.5,
-            begin_step=0 if (args.gmp or "one_shot") == "one_shot"
-            else args.steps // 10,
-            end_step=int(args.steps * 0.8),
-            recompute_every=max(1, args.steps // 20),
-            num_layers=cfg.n_layers,
-        )
+        gmp = gmp_schedule(args.gmp, args.sparsity, args.steps, cfg.n_layers)
         params = build_sparse_params(params, gmp.sparsity_at(0))
 
     opt_cfg = AdamWConfig(lr=args.lr)

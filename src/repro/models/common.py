@@ -65,9 +65,8 @@ def mm(x, w, *, inline=None):
 
 def mm_fused_qkv(x, wq, wk, wv):
     """The attention projections, through the decode megakernel when
-    eligible: one weight-stationary launch computes q/k/v, gathering each
-    fiber group's activations once per token instead of once per
-    projection.  Ineligible groups (dense weights, mixed formats,
+    eligible: one launch computes q/k/v instead of one per projection.
+    Ineligible groups (dense weights, mixed formats,
     prefill-shaped x, table veto) fall back to three :func:`mm` calls;
     outputs are bitwise-equal either way, so this is purely a launch-count
     optimization."""

@@ -158,7 +158,6 @@ def apply_moe_shmap(p, x, cfg: ModelConfig):
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist.compat import shard_map
     from repro.dist.sharding import active_rules
 
     ctx = active_rules()
@@ -178,7 +177,7 @@ def apply_moe_shmap(p, x, cfg: ModelConfig):
                 if kk in ("router", "wi", "wo")}
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(dp or None), {  # x over batch; weights: E over model,
             "router": P(),
             "wi": P("model", None, None),

@@ -96,7 +96,9 @@ def gmp_sparsity(s: GMPSchedule, step: int) -> float:
     ``sparsity_at_traced`` so the host-driven reference loop and the in-jit
     fast path quantize to the same level (and hence recompute bitwise-equal
     masks) at every step — a float64 host ramp would round top-k counts
-    differently on large tensors.
+    differently on large tensors.  The ramp ends at the float32-rounded
+    target, as the traced spelling does: the float64 target can lie below
+    the float32 levels just before it, and the ramp must not step down.
     """
     import numpy as _np
 
@@ -104,10 +106,10 @@ def gmp_sparsity(s: GMPSchedule, step: int) -> float:
         return s.target_sparsity if step >= s.begin_step else 0.0
     if step <= s.begin_step:
         return 0.0
+    tgt = _np.float32(s.target_sparsity)
     if step >= s.end_step:
-        return s.target_sparsity
+        return float(tgt)
     span = _np.float32(max(1, s.end_step - s.begin_step))
     frac = (_np.float32(step) - _np.float32(s.begin_step)) / span
     om = _np.float32(1.0) - frac
-    tgt = _np.float32(s.target_sparsity)
     return float(tgt * (_np.float32(1.0) - om * om * om))

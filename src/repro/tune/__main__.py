@@ -14,7 +14,7 @@ its section of the JSON tuning table:
   at the fig11 serving shapes,
 * on TPU (or with ``--pallas`` anywhere): the Pallas gemv tile config
   sweep (``gemv_pallas/...``) and the Pallas spmm schedule sweep
-  (``spmm_pallas/...`` — streamed double-buffer vs pipelined grid).
+  (``spmm_pallas/...`` — streamed vs windows-on-the-grid schedule).
 
 ``--quick`` shrinks the grid to a CI-sized smoke (a handful of shapes,
 few repetitions); the resulting table is still a *valid* table — just a

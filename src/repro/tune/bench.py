@@ -223,8 +223,8 @@ def tune_gemv_pallas(table: TuningTable, *, K: int = 1024, R: int = 1024,
                      tms: Sequence[int] = (128,),
                      depths: Sequence[int] = (64, 128, 256),
                      reps: int = 3, interpret: Optional[bool] = None) -> dict:
-    """Sweep the Pallas gemv output-tile width / packed-contraction depth
-    and record the fastest config for the shape bucket.  On CPU this runs
+    """Sweep the Pallas gemv activation-row tile / window depth and
+    record the fastest config for the shape bucket.  On CPU this runs
     the kernel in interpret mode — meaningful only as a smoke test, so the
     CLI gates it behind ``--pallas`` off-TPU."""
     from repro.kernels import ops as kops
@@ -256,8 +256,8 @@ def tune_spmm_pallas(table: TuningTable, *, K: int = 1024, R: int = 1024,
                      tns: Sequence[int] = (128,),
                      depths: Sequence[int] = (128,),
                      reps: int = 3, interpret: Optional[bool] = None) -> dict:
-    """Sweep the Pallas spmm schedule (streamed double-buffer vs pipelined
-    grid) and tile config, recording the fastest as the shape bucket's
+    """Sweep the Pallas spmm schedule (streamed: activation slab resident;
+    grid: windows on the grid) and tile config, recording the fastest as the shape bucket's
     ``spmm_pallas`` entry.  Interpret-mode timings off-TPU are smoke only
     (the CLI gates this behind ``--pallas`` there)."""
     from repro.kernels import ops as kops

@@ -9,11 +9,11 @@ answers every routing question the kernels ask:
   ``nmg_linear`` route on,
 * :func:`spmm_block_elems` — the gathered-operand cap of one XLA spmm
   block,
-* :func:`gemv_pallas_config` — the Pallas gemv output-tile / contraction
-  depth,
-* :func:`spmm_pallas_config` — the Pallas spmm column tile / contraction
-  depth and whether the streamed (double-buffered weight DMA) schedule is
-  used,
+* :func:`gemv_pallas_config` — the Pallas gemv activation-row tile /
+  decompression window depth,
+* :func:`spmm_pallas_config` — the Pallas spmm token tile / window depth
+  and whether the streamed schedule (activation slab resident, windows
+  looped in the kernel) is used,
 * :func:`fused_qkv` / :func:`fused_ffn` — whether the decode megakernels
   (``kernels/nmg_fused.py``) fuse eligible projection groups into one
   launch or fall back to per-projection gemv,
@@ -75,12 +75,12 @@ DEFAULT_DECODE_M_MAX = 16
 #: block — bounds peak memory like the old per-group scan did
 DEFAULT_SPMM_BLOCK_ELEMS = 1 << 22
 
-#: default Pallas gemv tile config (lane-width output tile, ~128-deep
-#: packed contractions)
+#: default Pallas gemv tile config: up to 128 activation rows per grid
+#: step, ~128 compressed positions per decompression window
 DEFAULT_GEMV_PALLAS = {"tm": 128, "target_depth": 128}
 
-#: default Pallas spmm config: lane-width column tile, ~128-deep packed
-#: contractions, and the double-buffered weight-streaming schedule
+#: default Pallas spmm config: 128 tokens per grid step, ~128 compressed
+#: positions per window, and the streamed schedule
 DEFAULT_SPMM_PALLAS = {"tn": 128, "target_depth": 128, "stream": True}
 
 #: decode megakernels fuse by default — eligibility (matching formats,

@@ -13,9 +13,11 @@ bucket and format the decision applies to, e.g.::
 
 Values are *decisions* (thresholds, block sizes, tile configs, measured
 conversion costs), never kernels themselves: a table can only change
-*which* registered path runs, so a stale or wrong table degrades
-performance, not correctness (the differential suite pins every route to
-bitwise-identical outputs).
+*which* registered path runs and with which tiles, so a stale or wrong
+table degrades performance, not correctness.  The differential suite pins
+the routes to bitwise-identical outputs; the one exception is a Pallas
+window depth (``target_depth``), which reassociates the f32 sum and is
+pinned within tolerance.
 
 A table file carries one device section per device kind, so a single
 cache file can serve a heterogeneous fleet; :meth:`TuningTable.load`
@@ -48,7 +50,8 @@ SCHEMA_VERSION = 1
 
 def device_kind() -> str:
     """Normalized device identity the table sections are keyed by, e.g.
-    ``cpu:cpu`` or ``tpu:tpu_v5e``."""
+    ``cpu:cpu`` or ``tpu:tpu_v5_lite`` (a TPU v5e reports ``device_kind``
+    "TPU v5 lite")."""
     dev = jax.devices()[0]
     kind = dev.device_kind.lower().replace(" ", "_")
     return f"{jax.default_backend()}:{kind}"

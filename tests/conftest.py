@@ -4,6 +4,10 @@
 import os
 import sys
 
+# tests never write a persistent compilation cache, not even through the
+# CLIs they run in subprocesses (which inherit this)
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
+
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax

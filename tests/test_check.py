@@ -217,3 +217,25 @@ def test_vmem_estimates_carry_table_provenance():
     assert est["source"] == "table"
     assert est["config"]["tm"] == 8
     assert est["bytes"] <= est["budget"]
+
+
+def test_unmodelled_device_with_sparse_weights_reports_r7():
+    # a device with no HW entry has no VMEM budget: the checker reports R7
+    # instead of estimating against another chip's numbers (or crashing)
+    from repro.check.program import build_program
+
+    prog = build_program("t/r7", lambda x: x, (jnp.ones((2, 96)),),
+                         model_dtype=jnp.float32,
+                         sparse_weights={"w": _gnm()}, decode_m=2,
+                         device_kind="tpu:tpu_v99")
+    assert prog.vmem_estimates == []
+    assert [d.rule for d in run_rules(prog)] == ["R7"]
+
+
+def test_hw_for_device_refuses_unknown_kind():
+    from repro.launch.hlo_analysis import hw_for_device
+
+    v5e = hw_for_device("tpu:tpu_v5_lite")   # what a v5e reports
+    assert v5e["peak_flops_bf16"] == 197e12 and v5e["hbm_bw"] == 819e9
+    with pytest.raises(KeyError, match="no entry"):
+        hw_for_device("tpu:tpu_v5e")

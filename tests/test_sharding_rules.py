@@ -113,7 +113,8 @@ def test_fixed_mask_value_and_mask_shard_identically():
 def test_sparse_leaf_shardings_round_trip_device_put():
     # on a real (1-device) mesh the spec tree must match the params treedef
     # exactly: tree_shardings + device_put round-trips sparse leaves
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     val = jnp.arange(32, dtype=jnp.float32).reshape(4, 8)
     mask = (val % 2 == 0)
     params = {"layers": {"mlp": {"wi": FixedMaskTensor(val, mask)}},

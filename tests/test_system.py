@@ -100,3 +100,49 @@ def test_dryrun_cli_smoke_cell():
     assert out.returncode == 0, out.stderr[-2000:]
     assert '"ok": true' in out.stdout
     assert '"dominant"' in out.stdout
+
+
+def test_chip_smoke_refuses_cpu():
+    """Without a TPU the chip smoke run fails and prints no result line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    root = os.path.join(os.path.dirname(__file__), "..")
+    out = subprocess.run([sys.executable, "chip_smoke.py"],
+                         capture_output=True, text=True, env=env,
+                         timeout=300, cwd=root)
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout
+    assert "no TPU" in out.stderr
+
+
+_CACHE_PROBE = """
+import jax, jax.numpy as jnp
+from repro.compile_cache import CHECKOUT_CACHE_DIR, enable_compile_cache
+print(enable_compile_cache())
+print(jax.config.jax_compilation_cache_dir)
+print(CHECKOUT_CACHE_DIR)
+jax.block_until_ready(jax.jit(lambda x: jnp.sin(x) @ x.T)(jnp.ones((8, 8))))
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "checkout"])
+def test_compile_cache_placement(tmp_path, from_env):
+    """$JAX_COMPILATION_CACHE_DIR is used as given (and receives the
+    entries); without it the cache goes to the fixed in-checkout path.  The
+    second case keeps the cache disabled, so no test writes the checkout."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+        env["JAX_ENABLE_COMPILATION_CACHE"] = "true"
+    else:
+        env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+    out = subprocess.run([sys.executable, "-c", _CACHE_PROBE],
+                         capture_output=True, text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    returned, configured, checkout = out.stdout.split()
+    want = str(tmp_path) if from_env else checkout
+    assert returned == configured == want
+    assert checkout.endswith(".jax_cache")
+    assert bool(list(tmp_path.iterdir())) == from_env
