@@ -476,24 +476,33 @@ def _decode_gqa_at(p, x, cfg, cache, pos, *, is_local):
     ring = bool(is_local and cfg.local_window and S_c <= cfg.local_window)
     wpos = (pv % S_c) if ring else pv
     rows = jnp.arange(B)
-    kc = cache["k"].at[rows, wpos].set(_q_cache(k[:, 0], cfg))
-    vc = cache["v"].at[rows, wpos].set(_q_cache(v[:, 0], cfg))
-    kd, vd = _dq_cache(kc, cfg), _dq_cache(vc, cfg)
-    if ring:
-        n_valid = jnp.minimum(pv + 1, S_c)
-        out = attn.decode_attention(q, kd, vd, n_valid,
-                                    softcap=cfg.attn_softcap)
-    else:
-        window = cfg.local_window if is_local else None
-        out = attn.decode_attention(q, kd, vd, pv + 1,
-                                    softcap=cfg.attn_softcap, window=window)
+    with jax.named_scope("kv.write"):
+        kc = cache["k"].at[rows, wpos].set(_q_cache(k[:, 0], cfg))
+        vc = cache["v"].at[rows, wpos].set(_q_cache(v[:, 0], cfg))
+    with jax.named_scope("attn.decode"):
+        kd, vd = _dq_cache(kc, cfg), _dq_cache(vc, cfg)
+        if ring:
+            n_valid = jnp.minimum(pv + 1, S_c)
+            out = attn.decode_attention(q, kd, vd, n_valid,
+                                        softcap=cfg.attn_softcap)
+        else:
+            window = cfg.local_window if is_local else None
+            out = attn.decode_attention(q, kd, vd, pv + 1,
+                                        softcap=cfg.attn_softcap,
+                                        window=window)
     y = _mm(out.reshape(B, 1, -1), p["wo"])
     return y, {"k": kc, "v": vc}
 
 
+@jax.named_scope("decode.step")
 def decode_step(params, cfg: ModelConfig, token, cache, pos):
     """token [B, 1] int32; pos [] or [B] int32 (per-slot positions for the
-    continuous-batching engine); returns (logits [B, V], new cache)."""
+    continuous-batching engine); returns (logits [B, V], new cache).
+
+    Named scopes tag its device ops for a profiler trace: ``decode.step``
+    the embedding, final norm and logits, ``decode.layers`` the layer
+    scan's own slicing of the stacked cache and restacking of the updated
+    one, ``decode.layer`` each layer's body."""
     x = jnp.take(params["embedding"], token, axis=0)
     x = x * jnp.asarray(jnp.sqrt(1.0 * cfg.d_model), x.dtype)
     x = logical_constraint(x, ("batch", None, None))
@@ -501,6 +510,7 @@ def decode_step(params, cfg: ModelConfig, token, cache, pos):
     pair = cfg.layer_pattern == "alt_local_global"
     all_local = cfg.layer_pattern == "local"
 
+    @jax.named_scope("decode.layer")
     def body(carry, xs):
         h = carry
         lp, c = xs
@@ -513,7 +523,8 @@ def decode_step(params, cfg: ModelConfig, token, cache, pos):
         h, c2 = _decode_layer(lp, h, cfg, c, pos, is_local=all_local)
         return h, c2
 
-    x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
+    with jax.named_scope("decode.layers"):
+        x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
     x = _rms(x, params["final_norm"])
     logits = logits_of(params, cfg, x)[:, 0]
     return logits, new_cache

@@ -7,12 +7,16 @@ visible on a common timeline.  This module is the timeline:
 
 * :func:`span` — a context manager that records one *complete* interval
   (Chrome ``ph: "X"`` semantics: begin timestamp + duration) on a named
-  track, with arbitrary attributes;
+  track, with arbitrary attributes.  The same span also enters a
+  ``jax.profiler.TraceAnnotation``, so while a profiler trace is running
+  it lands on the profiler's host plane too, on the device trace's clock:
+  one span API writes both timelines;
 * :func:`event` — an instantaneous marker (``ph: "i"``) for decisions
   (tier switch, watchdog trip, kernel route, fault injection);
 * :func:`complete` — a retroactive span for intervals whose endpoints the
   caller already timestamped (the engine knows a request's arrival /
-  admission / finish times; it emits the "queued" span at admission);
+  admission / finish times; it emits the "queued" span at admission).
+  Recorder-only: a profiler trace cannot take an interval after the fact;
 * the **flight recorder** — a bounded ring buffer (``collections.deque``
   with ``maxlen``) holding the most recent ``capacity`` records.  Memory
   is bounded by construction and the oldest records are overwritten
@@ -23,8 +27,10 @@ Cost model: tracing is **off by default** and every recording function
 checks the module-level ``_ENABLED`` flag first.  When disabled,
 :func:`event` returns immediately and :func:`span` returns a shared
 no-op context-manager singleton — no record, no recorder touch, no
-allocation beyond the caller's own kwargs.  When enabled, a record is
-one small tuple appended to a deque; timestamps come from
+annotation, no allocation beyond the caller's own kwargs (JAX is not
+even imported).  When enabled, a record is one small tuple appended to a
+deque, and a span also enters and exits one ``TraceAnnotation`` (which
+costs next to nothing while no profiler trace runs); timestamps come from
 ``time.perf_counter`` (monotonic), stored as integer microseconds
 relative to the recorder epoch set by :func:`enable`.
 
@@ -138,7 +144,8 @@ def counter_event(name: str, track: str, attrs: Optional[dict]) -> None:
 def complete(name: str, t0_s: float, t1_s: float, track: str = "engine",
              **attrs) -> None:
     """Record a retroactive complete span from absolute ``perf_counter``
-    seconds (the engine's ``_t0 + relative`` timestamps)."""
+    seconds (the engine's ``_t0 + relative`` timestamps).  Flight recorder
+    only: unlike :func:`span` it writes nothing to a profiler trace."""
     if not _ENABLED:
         return
     ts = int((t0_s - _EPOCH) * 1e6)
@@ -147,10 +154,11 @@ def complete(name: str, t0_s: float, t1_s: float, track: str = "engine",
 
 
 class _Span:
-    """Live span: timestamps on enter, records one complete event on exit.
+    """Live span: timestamps on enter, records one complete event on exit,
+    and holds a ``jax.profiler.TraceAnnotation`` open in between.
     Exceptions propagate; the span still records (with ``error`` set)."""
 
-    __slots__ = ("name", "track", "attrs", "t0")
+    __slots__ = ("name", "track", "attrs", "t0", "ann")
 
     def __init__(self, name, track, attrs):
         self.name = name
@@ -158,13 +166,24 @@ class _Span:
         self.attrs = attrs
 
     def __enter__(self):
+        from jax.profiler import TraceAnnotation  # only traced spans get here
+
+        self.ann = TraceAnnotation(self.name, **self.attrs)
+        self.ann.__enter__()
         self.t0 = _now_us()
         return self
 
+    def set(self, **attrs) -> None:
+        """Add attributes known only inside the span (a count reached at
+        its boundary); both timelines carry them."""
+        self.attrs.update(attrs)
+        self.ann.set_metadata(**attrs)
+
     def __exit__(self, exc_type, exc, tb):
+        self.ann.__exit__(exc_type, exc, tb)
         attrs = self.attrs
         if exc_type is not None:
-            attrs = dict(attrs or ())
+            attrs = dict(attrs)
             attrs["error"] = exc_type.__name__
         if _ENABLED:  # disabled mid-span: drop rather than half-record
             _append(("X", self.name, self.track, self.t0,
@@ -181,6 +200,9 @@ class _NullSpan:
     def __enter__(self):
         return self
 
+    def set(self, **attrs) -> None:
+        pass
+
     def __exit__(self, exc_type, exc, tb):
         return False
 
@@ -189,8 +211,10 @@ _NULL_SPAN = _NullSpan()
 
 
 def span(name: str, track: str = "engine", **attrs):
-    """Context manager recording one complete span on ``track``.  Returns
-    the shared no-op singleton when tracing is disabled."""
+    """Context manager recording one complete span on ``track``, and the
+    same interval as a ``jax.profiler.TraceAnnotation`` (with ``attrs`` as
+    its stats) for a profiler trace running meanwhile.  Returns the shared
+    no-op singleton when tracing is disabled."""
     if not _ENABLED:
         return _NULL_SPAN
     return _Span(name, track, attrs)

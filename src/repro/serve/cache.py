@@ -137,6 +137,7 @@ class SlotKVCache:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("kv.view")
 def paged_view(cfg: ModelConfig, pool, table, page_size: int):
     """Gather the slot-major logical cache out of the paged pool.
 
@@ -160,6 +161,7 @@ def paged_view(cfg: ModelConfig, pool, table, page_size: int):
     return jax.tree_util.tree_map(leaf, pool, kinds)
 
 
+@jax.named_scope("kv.commit")
 def paged_commit(cfg: ModelConfig, pool, view, table, pos, n_steps: int,
                  page_size: int, num_pages: int):
     """Write back what a decode chunk changed: for each slot, the
@@ -268,7 +270,8 @@ def _jit_paged_prefill(cfg: ModelConfig, page_size: int, num_pages: int):
                 return pl.at[:, slot].set(piece)
             return pl.at[:, phys, rowi].set(piece)   # [L, S, ...] rows
 
-        pool = jax.tree_util.tree_map(leaf, pool, contribs, kinds)
+        with jax.named_scope("kv.prefill_write"):
+            pool = jax.tree_util.tree_map(leaf, pool, contribs, kinds)
         return logits, pool
 
     return jax.jit(run, donate_argnums=(2,))

@@ -100,9 +100,10 @@ def _decode_chunk_fn(cfg: ModelConfig, n_steps: int):
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)   # [B] on device
             return (nxt[:, None], cache, pos + 1), nxt
 
-        (_, cache, _), toks = jax.lax.scan(
-            body, (tok, cache, pos), None, length=n_steps
-        )
+        with jax.named_scope("decode.chunk"):
+            (_, cache, _), toks = jax.lax.scan(
+                body, (tok, cache, pos), None, length=n_steps
+            )
         return toks, cache
 
     return chunk
@@ -194,9 +195,10 @@ def _jit_paged_decode_chunk(cfg: ModelConfig, page_size: int,
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)
             return (nxt[:, None], view, pv + 1), nxt
 
-        (_, view, _), toks = jax.lax.scan(
-            body, (tok, view, pos), None, length=n_steps
-        )
+        with jax.named_scope("decode.chunk"):
+            (_, view, _), toks = jax.lax.scan(
+                body, (tok, view, pos), None, length=n_steps
+            )
         pool = paged_commit(cfg, pool, view, table, pos, n_steps,
                             page_size, num_pages)
         return toks, pool
@@ -475,32 +477,34 @@ class ServeEngine:
         prompt = jnp.asarray(req.prompt, jnp.int32)[None]
         if self.faults is not None:
             self.faults.admission_delay()
-        t_pre = self._now()
-        if self.paged:
-            logits = self.kv.admit(self.params, prompt, slot)
-            if logits is None:
-                return False
-        else:
-            logits = self.kv.write_prefill(self.params, prompt, slot)
         S = int(req.prompt.size)
-        if self._latency is not None:
-            self._latency.observe_prefill(S, self._now() - t_pre)
-        if obs.enabled():
-            # the request's lifecycle row: time spent queued (arrival to
-            # admission), then the prefill that admitted it
-            obs.complete("queued", self._abs(req.arrival_time),
-                         self._abs(now), f"req:{req.uid}", uid=req.uid)
-            obs.complete("prefill", self._abs(t_pre),
-                         self._abs(self._now()), f"req:{req.uid}",
-                         uid=req.uid, slot=slot, prompt_len=S)
-        # token i (1-based) is written to the cache at position S + i - 1,
-        # so generating N tokens needs S + N - 1 <= max_seq_len
-        max_new = min(req.max_new_tokens, self.max_seq_len - S + 1)
-        st = _SlotState(
-            req=req, tokens=[], token_times=[], admitted_time=now,
-            rng=np.random.default_rng(req.sampling.seed), max_new=max_new,
-        )
-        tok = sample_token(np.asarray(logits[0]), req.sampling, st.rng)
+        # the span ends after the first token's fetch, so it holds the
+        # device's prefill and not only its enqueue
+        with obs.span("engine.admit", "engine", uid=req.uid, prompt_len=S,
+                      slot=slot):
+            t_pre = self._now()
+            if self.paged:
+                logits = self.kv.admit(self.params, prompt, slot)
+                if logits is None:
+                    return False
+            else:
+                logits = self.kv.write_prefill(self.params, prompt, slot)
+            if self._latency is not None:
+                self._latency.observe_prefill(S, self._now() - t_pre)
+            if obs.enabled():
+                # the request's lifecycle row: time spent queued (arrival
+                # to admission)
+                obs.complete("queued", self._abs(req.arrival_time),
+                             self._abs(now), f"req:{req.uid}", uid=req.uid)
+            # token i (1-based) is written to the cache at position
+            # S + i - 1, so generating N tokens needs S + N - 1 <= max_seq_len
+            max_new = min(req.max_new_tokens, self.max_seq_len - S + 1)
+            st = _SlotState(
+                req=req, tokens=[], token_times=[], admitted_time=now,
+                rng=np.random.default_rng(req.sampling.seed),
+                max_new=max_new,
+            )
+            tok = sample_token(np.asarray(logits[0]), req.sampling, st.rng)
         st.tokens.append(tok)
         st.token_times.append(self._now())
         self._slots[slot] = st
@@ -680,117 +684,75 @@ class ServeEngine:
         (``decode_chunk`` steps device-resident when every active request
         is greedy, one host-paced step otherwise).  Returns the number of
         tokens produced (0 when the engine idled)."""
-        now = self._now()
-        produced = 0
-        for req in self.queue.expired(now):
-            self._finish_unserved(req, now, "timeout")
-        ctrl = self._controller
-        if ctrl is not None:
-            ctrl.begin_step(now, len(self.queue))
-            if self.tiers is not None:
-                self.set_tier(ctrl.tier_index,
-                              reason=f"slo:{ctrl.last_reason}")
-            if ctrl.should_shed(len(self.queue)):
-                for req in self.queue.shed(ctrl.shed_keep()):
-                    self._finish_unserved(req, now, "shed")
-        free = self.free_slots()
-        budget = len(free) if ctrl is None \
-            else ctrl.admission_budget(len(free))
-        while free and budget > 0:
-            req = self.queue.pop_ready(now)
-            if req is None:
-                break
-            if req.deadline is not None and self._latency is not None:
-                # admission-time cost prediction: a request that cannot
-                # possibly finish inside its deadline times out now,
-                # without burning a slot on doomed work
-                est = self._latency.request_s(
-                    int(req.prompt.size),
-                    min(req.max_new_tokens,
-                        self.max_seq_len - int(req.prompt.size) + 1))
-                if est == est and now + est > req.deadline:
-                    self._finish_unserved(req, now, "timeout")
-                    continue
-            try:
-                admitted = self._admit(free[0], req, now)
-            except PromptTooLongError:
-                self._reject(req, now)
-                continue  # slot stays free for the next ready request
-            if not admitted:
-                # out of pages: the request returns to the queue head and
-                # admission stops — live slots are untouched, and pages
-                # will free up as active requests finish
-                self.queue.push_front(req)
-                self.stats["deferred_admissions"] += 1
-                break
-            free.pop(0)
-            budget -= 1
-            produced += 1  # the first token sampled from prefill logits
-        active = [i for i, s in enumerate(self._slots) if s is not None]
-        self.stats["peak_active"] = max(self.stats["peak_active"],
-                                        len(active))
-        if not active:
+        with obs.span("engine.step", "engine", queue=len(self.queue)):
+            now = self._now()
+            produced = 0
+            for req in self.queue.expired(now):
+                self._finish_unserved(req, now, "timeout")
+            ctrl = self._controller
+            if ctrl is not None:
+                ctrl.begin_step(now, len(self.queue))
+                if self.tiers is not None:
+                    self.set_tier(ctrl.tier_index,
+                                  reason=f"slo:{ctrl.last_reason}")
+                if ctrl.should_shed(len(self.queue)):
+                    for req in self.queue.shed(ctrl.shed_keep()):
+                        self._finish_unserved(req, now, "shed")
+            free = self.free_slots()
+            budget = len(free) if ctrl is None \
+                else ctrl.admission_budget(len(free))
+            while free and budget > 0:
+                req = self.queue.pop_ready(now)
+                if req is None:
+                    break
+                if req.deadline is not None and self._latency is not None:
+                    # admission-time cost prediction: a request that cannot
+                    # possibly finish inside its deadline times out now,
+                    # without burning a slot on doomed work
+                    est = self._latency.request_s(
+                        int(req.prompt.size),
+                        min(req.max_new_tokens,
+                            self.max_seq_len - int(req.prompt.size) + 1))
+                    if est == est and now + est > req.deadline:
+                        self._finish_unserved(req, now, "timeout")
+                        continue
+                try:
+                    admitted = self._admit(free[0], req, now)
+                except PromptTooLongError:
+                    self._reject(req, now)
+                    continue  # slot stays free for the next ready request
+                if not admitted:
+                    # out of pages: the request returns to the queue head
+                    # and admission stops — live slots are untouched, and
+                    # pages will free up as active requests finish
+                    self.queue.push_front(req)
+                    self.stats["deferred_admissions"] += 1
+                    break
+                free.pop(0)
+                budget -= 1
+                produced += 1  # the first token sampled from prefill logits
+            active = [i for i, s in enumerate(self._slots) if s is not None]
+            self.stats["peak_active"] = max(self.stats["peak_active"],
+                                            len(active))
+            if active:
+                T = self.decode_chunk if ctrl is None \
+                    else ctrl.decode_chunk(self.decode_chunk)
+                if (T <= 1 or self._decode_chunk is None
+                        or self._force_single
+                        or not all(self._slots[s].req.sampling.greedy
+                                   for s in active)):
+                    T = 1
+                produced += self._decode_active(active, T)
             self._count_tokens(produced)
             return produced
-        T = self.decode_chunk if ctrl is None \
-            else ctrl.decode_chunk(self.decode_chunk)
-        if (T > 1 and self._decode_chunk is not None
-                and not self._force_single
-                and all(self._slots[s].req.sampling.greedy for s in active)):
-            produced += self._step_chunked(active, T)
-        else:
-            produced += self._step_single(active)
-        self._count_tokens(produced)
-        return produced
-
-    def _step_single(self, active) -> int:
-        """Per-token reference path: one decode step, host-side sampling."""
-        produced = 0
-        if self.paged:
-            active = self._ensure_decode_pages(active, 1)
-            if not active:
-                return 0
-        step_idx = self._decode_calls
-        self._decode_calls += 1
-        self._fault_gate(step_idx)
-        t0 = self._now()
-        tok = jnp.asarray(self._tok[:, None])
-        pos = jnp.asarray(self._pos)
-        if self.paged:
-            logits, self.kv.data = self._decode(
-                self.params, tok, self.kv.data, self.kv.device_table(), pos)
-        else:
-            logits, self.kv.data = self._decode(self.params, tok,
-                                                self.kv.data, pos)
-        logits_np = np.asarray(logits)
-        self._fault_post(step_idx, self._now() - t0)
-        t = self._now()
-        if self._controller is not None:
-            self._controller.observe_decode(t - t0, 1)
-        if obs.enabled():
-            obs.complete("decode_call", self._abs(t0), self._abs(t),
-                         "engine", call=step_idx, steps=1,
-                         n_active=len(active), tier=self.tier_idx)
-            for slot in active:
-                obs.complete("decode_step", self._abs(t0), self._abs(t),
-                             f"req:{self._slots[slot].req.uid}",
-                             call=step_idx, tier=self.tier_idx)
-        for slot in active:
-            st = self._slots[slot]
-            nxt = sample_token(logits_np[slot], st.req.sampling, st.rng)
-            st.tokens.append(nxt)
-            st.token_times.append(t)
-            self._pos[slot] += 1
-            self._tok[slot] = nxt
-            produced += 1
-            if self._stopped(st, nxt):
-                self._finish(slot)
-        return produced
 
     def _chunk_fn(self, T: int):
-        """The jitted chunk program for ``T`` steps — the pre-bound default
-        for the base chunk, the module-level cache (same compiled
-        executables) for the controller's shrunk chunk."""
+        """The jitted decode program for ``T`` steps: the single-step program
+        for 1, the pre-bound default for the base chunk, the module-level
+        cache (same compiled executables) for the controller's shrunk
+        chunk."""
+        if T == 1:
+            return self._decode
         if T == self.decode_chunk:
             return self._decode_chunk
         if self.paged:
@@ -798,10 +760,12 @@ class ServeEngine:
                                            self.kv.num_pages, T)
         return _jit_decode_chunk(self.cfg, T)
 
-    def _step_chunked(self, active, T: Optional[int] = None) -> int:
-        """Greedy fast path: ``T`` (default ``decode_chunk``) steps in one
-        jit call with on-device argmax sampling, then a single chunked
-        host fetch.
+    def _decode_active(self, active, T: int) -> int:
+        """One decode call over the active slots, then the host's token
+        bookkeeping.  ``T > 1`` is the greedy fast path: ``T`` steps in one
+        jit call with on-device argmax sampling and a single chunked host
+        fetch.  ``T == 1`` is the per-token reference path: one decode step,
+        host-side sampling.
 
         The device loop always runs the full fixed-length chunk (one
         compiled program, no per-remaining-budget recompiles); tokens a
@@ -809,56 +773,61 @@ class ServeEngine:
         on the host.  Overshoot cache writes land in positions of slots
         that are about to be freed and are either overwritten by the next
         occupant's prefill/decode writes or masked out by the per-slot
-        valid-prefix attention mask, so they are never read.  Per-token
-        timestamps spread the measured chunk latency uniformly across the
-        chunk's tokens (the stream's average decode cadence)."""
-        produced = 0
-        T = self.decode_chunk if T is None else T
-        if self.paged:
-            active = self._ensure_decode_pages(active, T)
-            if active is None:
-                # a lone slot can't fit a whole chunk's pages: degrade to
-                # the one-page-at-a-time path until a finish frees pages
-                self._force_single = True
-                active = [i for i, s in enumerate(self._slots)
-                          if s is not None]
-                return self._step_single(active) if active else 0
+        valid-prefix attention mask, so they are never read."""
+        with obs.span("engine.decode", "engine") as span:
+            with obs.span("engine.decode.prepare", "engine"):
+                if self.paged:
+                    ready = self._ensure_decode_pages(active, T)
+                    if ready is None:
+                        # a lone slot can't fit a whole chunk's pages:
+                        # degrade to the one-page-at-a-time path until a
+                        # finish frees pages
+                        self._force_single = True
+                        T = 1
+                        ready = self._ensure_decode_pages(
+                            [i for i, s in enumerate(self._slots)
+                             if s is not None], 1)
+                    active = ready
+                tok = jnp.asarray(self._tok[:, None])
+                pos = jnp.asarray(self._pos)
+                table = (self.kv.device_table(),) if self.paged else ()
             if not active:
                 return 0
-        step_idx = self._decode_calls
-        self._decode_calls += 1
-        self._fault_gate(step_idx)
-        fn = self._chunk_fn(T)
-        t0 = self._now()
-        if self.paged:
-            toks, self.kv.data = fn(
-                self.params, jnp.asarray(self._tok[:, None]), self.kv.data,
-                self.kv.device_table(), jnp.asarray(self._pos),
-            )
-        else:
-            toks, self.kv.data = fn(
-                self.params, jnp.asarray(self._tok[:, None]), self.kv.data,
-                jnp.asarray(self._pos),
-            )
-        toks_np = np.asarray(toks)  # [T, max_slots] — one host sync
-        self._fault_post(step_idx, self._now() - t0)
-        t1 = self._now()
-        if self._controller is not None:
-            self._controller.observe_decode(t1 - t0, T)
-        if obs.enabled():
-            obs.complete("decode_call", self._abs(t0), self._abs(t1),
-                         "engine", call=step_idx, steps=T,
-                         n_active=len(active), tier=self.tier_idx)
-            for slot in active:
-                obs.complete("decode_chunk", self._abs(t0), self._abs(t1),
-                             f"req:{self._slots[slot].req.uid}",
-                             call=step_idx, steps=T, tier=self.tier_idx)
+            step_idx = self._decode_calls
+            self._decode_calls += 1
+            span.set(call=step_idx, steps=T, n_active=len(active))
+            self._fault_gate(step_idx)
+            fn = self._chunk_fn(T)
+            t0 = self._now()
+            out, self.kv.data = fn(self.params, tok, self.kv.data, *table,
+                                   pos)
+            with obs.span("engine.decode.fetch", "engine"):
+                # [T, max_slots] tokens or [max_slots, V] logits — one sync
+                out = np.asarray(out)
+            self._fault_post(step_idx, self._now() - t0)
+            t1 = self._now()
+            if self._controller is not None:
+                self._controller.observe_decode(t1 - t0, T)
+        with obs.span("engine.emit", "engine"):
+            return self._emit(active, out, T, t0, t1)
+
+    def _emit(self, active, out, T: int, t0: float, t1: float) -> int:
+        """Append each active slot's new tokens — a chunk's on-device argmax
+        tokens, or one token sampled on the host from a single step's
+        logits — until its stop condition.  Per-token timestamps spread the
+        measured call latency uniformly across a chunk's tokens (the
+        stream's average decode cadence)."""
+        produced = 0
         for slot in active:
             st = self._slots[slot]
             for t in range(T):
-                nxt = int(toks_np[t, slot])
+                if T == 1:
+                    nxt = sample_token(out[slot], st.req.sampling, st.rng)
+                    st.token_times.append(t1)
+                else:
+                    nxt = int(out[t, slot])
+                    st.token_times.append(t0 + (t + 1) * (t1 - t0) / T)
                 st.tokens.append(nxt)
-                st.token_times.append(t0 + (t + 1) * (t1 - t0) / T)
                 self._pos[slot] += 1
                 self._tok[slot] = nxt
                 produced += 1
@@ -897,15 +866,16 @@ class ServeEngine:
                 # warp virtual time to the arrival so the loop always
                 # makes progress
                 nxt = self.queue.next_arrival()
-                while nxt is not None:
-                    remaining = nxt - self._now()
-                    if remaining <= 0:
-                        break
-                    t_before = self._clock()
-                    time.sleep(min(remaining, 0.05))
-                    if self._clock() <= t_before:
-                        self._t0 -= remaining
-                        break
+                with obs.span("engine.wait", "engine"):
+                    while nxt is not None:
+                        remaining = nxt - self._now()
+                        if remaining <= 0:
+                            break
+                        t_before = self._clock()
+                        time.sleep(min(remaining, 0.05))
+                        if self._clock() <= t_before:
+                            self._t0 -= remaining
+                            break
         return sorted(self._outputs[first_new:], key=lambda o: o.uid)
 
     def metrics(self, *, label: str = "serve") -> ServeMetrics:

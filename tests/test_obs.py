@@ -3,11 +3,16 @@ schema/nesting validation, registry semantics (in-place reset, event
 emission, the muted bulk-restore path), exporters, the nan-safe metrics
 edge cases, and the engine-level guarantees the observability PR ships
 on: tracing changes no tokens, and a warm engine records no new JIT
-traces with the recorder on.
+traces with the recorder on.  Spans also reach a running profiler's host
+plane (one span API, two timelines), and the serving programs carry the
+named scopes a device trace is reduced by.
 """
 
+import collections
 import dataclasses
+import glob
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -274,8 +279,16 @@ def test_tracing_changes_no_tokens_and_emits_lifecycle_spans(setup):
     on = ServeEngine(params, cfg, **ekw).run(_reqs(cfg))
     assert [o.tokens for o in on] == [o.tokens for o in off]
     names = {r[1] for r in obs.records()}
-    assert {"queued", "prefill", "finish"} <= names
-    assert "decode_chunk" in names or "decode_step" in names
+    assert {"queued", "finish", "engine.step", "engine.admit",
+            "engine.decode", "engine.decode.prepare",
+            "engine.decode.fetch", "engine.emit"} <= names
+    # the retroactive per-request decode rows are gone: the live engine
+    # spans carry the call's counters instead
+    assert not names & {"prefill", "decode_call", "decode_chunk",
+                        "decode_step"}
+    decodes = [r for r in obs.records() if r[1] == "engine.decode"]
+    assert all(r[5]["steps"] in (1, 4) and 1 <= r[5]["n_active"] <= 2
+               for r in decodes)
     # every request got its own track row, and the export validates
     tracks = {r[2] for r in obs.records()}
     assert {f"req:{u}" for u in range(3)} <= tracks
@@ -300,3 +313,146 @@ def test_warm_engine_records_no_new_jit_traces_with_recorder_on(setup):
     assert not [r for r in obs.records() if r[1] == "jit_trace"]
     switches = [r for r in obs.records() if r[1] == "tier_switch"]
     assert switches and switches[-1][5]["tier_to"] == "1:4:8-gr64"
+
+
+# ---------------------------------------------------------------------------
+# one span API, two timelines; named scopes in the serving programs
+# ---------------------------------------------------------------------------
+
+PAGED = dict(max_slots=2, max_seq_len=32, decode_chunk=4, paged=True,
+             page_size=8)
+
+
+def _host_spans(log_dir) -> dict:
+    """``{name: [(start_ns, end_ns, stats)]}`` of the host-plane events of
+    the profile written under ``log_dir``."""
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    spans = collections.defaultdict(list)
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    spans[e.name].append((e.start_ns,
+                                          e.start_ns + e.duration_ns,
+                                          dict(e.stats)))
+    return spans
+
+
+def _within(inner, outers) -> bool:
+    return any(o[0] <= inner[0] and inner[1] <= o[1] for o in outers)
+
+
+def test_engine_spans_land_on_the_profiler_host_plane(setup, tmp_path,
+                                                      monkeypatch):
+    """With tracing on, the engine's live spans are profiler annotations
+    too, nested as the loop runs: a step holds its admissions, its decode
+    call (page preparation, then the blocking fetch) and the token
+    bookkeeping; an admission ends after its first token was fetched and
+    sampled, so it holds the device's prefill, not only its enqueue."""
+    cfg, params = setup
+    from repro.serve import engine as engine_mod
+
+    sample = engine_mod.sample_token
+
+    def marked(*a, **kw):
+        with jax.profiler.TraceAnnotation("test.first_token"):
+            return sample(*a, **kw)
+
+    monkeypatch.setattr(engine_mod, "sample_token", marked)
+    ServeEngine(params, cfg, **PAGED).run(_reqs(cfg))  # compile untraced
+    obs.enable()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        ServeEngine(params, cfg, **PAGED).run(_reqs(cfg))
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(tmp_path)
+    steps, admits = spans["engine.step"], spans["engine.admit"]
+    decodes = spans["engine.decode"]
+    assert len(admits) == 3 and decodes and spans["engine.emit"]
+    assert all(_within(a, steps) for a in admits)
+    assert all(_within(d, steps) for d in decodes)
+    assert all(_within(e, steps) for e in spans["engine.emit"])
+    for inner in ("engine.decode.prepare", "engine.decode.fetch"):
+        assert len(spans[inner]) == len(decodes)
+        assert all(_within(x, decodes) for x in spans[inner])
+    assert all(d[2]["steps"] == 4 and d[2]["n_active"] in (1, 2)
+               for d in decodes)
+    assert sorted(d[2]["call"] for d in decodes) == list(range(len(decodes)))
+    assert sorted(a[2]["uid"] for a in admits) == [0, 1, 2]
+    assert all(_within(t, admits) for t in spans["test.first_token"])
+    assert len(spans["test.first_token"]) == len(admits)
+
+
+def test_disabled_spans_build_no_annotation_and_change_no_tokens(
+        setup, monkeypatch):
+    cfg, params = setup
+    built = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            built.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    assert obs.span("engine.step", queue=1) is obs.span("engine.decode")
+    off = ServeEngine(params, cfg, **PAGED).run(_reqs(cfg))
+    assert built == []
+    obs.enable()
+    on = ServeEngine(params, cfg, **PAGED).run(_reqs(cfg))
+    assert [o.tokens for o in on] == [o.tokens for o in off]
+    assert {"engine.step", "engine.admit", "engine.decode"} <= set(built)
+
+
+def test_span_is_a_profiler_annotation_and_complete_is_not(monkeypatch):
+    """Enabled, a span enters one ``TraceAnnotation`` carrying its attrs,
+    including those set inside it; the retroactive ``complete`` writes the
+    flight recorder only."""
+    built = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            built.append((name, kw))
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    obs.enable()
+    with obs.span("engine.decode", "engine", call=0) as sp:
+        sp.set(steps=8, n_active=3)
+    obs.complete("queued", 0.0, 1.0, "req:0")
+    assert built == [("engine.decode", {"call": 0})]
+    span, queued = obs.records()
+    assert span[1] == "engine.decode"
+    assert span[5] == {"call": 0, "steps": 8, "n_active": 3}
+    assert queued[1] == "queued"
+
+
+def _scopes(lowered) -> set:
+    names = re.findall(r'op_name="([^"]*)"',
+                       lowered.as_text("hlo", debug_info=True))
+    return {part for n in names for part in n.split("/")}
+
+
+def test_serving_programs_carry_named_scopes(setup):
+    """The paged decode chunk (``jit_chunk``) and the paged prefill
+    (``jit_run``) name what moves the cache and what computes, for a
+    device trace's ``tf_op`` to be read by."""
+    from repro.serve import cache as cache_mod, engine as engine_mod
+
+    cfg, params = setup
+    kv = cache_mod.PagedKVCache(cfg, 2, 32, page_size=8)
+    chunk = engine_mod._jit_paged_decode_chunk(cfg, 8, kv.num_pages, 4)
+    lowered = chunk.lower(params, jnp.zeros((2, 1), jnp.int32), kv.data,
+                          kv.device_table(), jnp.zeros(2, jnp.int32))
+    assert {"kv.view", "kv.commit", "kv.write", "decode.chunk",
+            "decode.step", "decode.layers", "decode.layer",
+            "attn.decode"} <= _scopes(lowered)
+    prefill = cache_mod._jit_paged_prefill(cfg, 8, kv.num_pages)
+    lowered = prefill.lower(params, jnp.zeros((1, 8), jnp.int32), kv.data,
+                            jnp.asarray(kv.table[0]), jnp.int32(0),
+                            jnp.int32(0))
+    assert "kv.prefill_write" in _scopes(lowered)
