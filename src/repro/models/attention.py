@@ -30,6 +30,9 @@ __all__ = [
     "apply_mla",
     "decode_mla",
     "pos_vec",
+    "append_row",
+    "appended_rows",
+    "read_appended",
 ]
 
 NEG_INF = -1e30
@@ -45,6 +48,48 @@ def pos_vec(pos, B: int) -> jnp.ndarray:
     if p.ndim == 0:
         p = jnp.broadcast_to(p[None], (B,))
     return p
+
+
+# ---------------------------------------------------------------------------
+# append buffer: a decode chunk's new cache rows, beside a read-only history
+# ---------------------------------------------------------------------------
+
+
+def append_row(buf, row, start, pos):
+    """Write each slot's new row into its append buffer: ``buf`` [B, T,
+    ...] holds the rows of positions ``start .. start + T - 1``; ``row``
+    [B, ...] is position ``pos`` (start <= pos < start + T)."""
+    return buf.at[jnp.arange(buf.shape[0]), pos - start].set(row)
+
+
+def appended_rows(start, pos, T: int, S: int, *, ring: bool = False):
+    """[B, T] history rows of the append buffer's rows once the step at
+    ``pos`` has written: buffer row t holds position start + t, at row
+    (start + t) % S of a ring (window-sized) history.  Rows not yet
+    written, and ring rows a later position overwrote, map to the
+    out-of-range row S, which a scatter drops — as it drops positions past
+    a linear history's end."""
+    t = jnp.arange(T, dtype=jnp.int32)[None, :]
+    step = (pos - start)[:, None]              # the row written at pos
+    p = start[:, None] + t
+    keep = t <= step
+    if ring:
+        keep &= t > step - S                   # not overwritten by t + S
+        p = p % S
+    return jnp.where(keep, p, S)
+
+
+def read_appended(history, buf, start, pos, *, ring: bool = False):
+    """The cache rows a decode step attends over, without writing the
+    history: row s of slot b is ``buf[b, p - start[b]]`` where the position
+    p that row holds was written this chunk (start[b] <= p <= pos[b]), and
+    ``history[b, s]`` otherwise.  history [B, S, ...] is read-only, buf
+    [B, T, ...] the append buffer.  The buffer's written rows are scattered
+    into a copy of the history (:func:`appended_rows`), so the result is
+    bitwise the history a per-row write would have made."""
+    B, S, T = history.shape[0], history.shape[1], buf.shape[1]
+    rows = appended_rows(start, pos, T, S, ring=ring)
+    return history.at[jnp.arange(B)[:, None], rows].set(buf)
 
 
 # ---------------------------------------------------------------------------
@@ -338,10 +383,13 @@ def apply_mla(p, x, cfg: ModelConfig, *, positions=None, causal=True):
     return mm(out.reshape(B, S, -1), p["wo"]), ckv, k_rope
 
 
-def decode_mla(p, x, cfg: ModelConfig, cache, pos, q_cache=None,
-               dq_cache=None):
+def decode_mla(p, x, cfg: ModelConfig, history, buf, start, pos,
+               q_cache=None, dq_cache=None):
     """Absorbed-MLA decode over the *compressed* cache (the serving memory
-    win that motivates MLA): cache = {'ckv' [B, S, r], 'kr' [B, S, rd]}.
+    win that motivates MLA): history = {'ckv' [B, S, r], 'kr' [B, S, rd]},
+    read-only; buf the same leaves as [B, T, ...] append buffers (see
+    :func:`read_appended`), into which this step's latents are written.
+    Returns (y, updated buf).
 
     Scores in latent space: q_nope is absorbed through W_uk so attention
     reads c_kv directly; output re-expands through W_uv.
@@ -364,9 +412,11 @@ def decode_mla(p, x, cfg: ModelConfig, cache, pos, q_cache=None,
                 cfg.rope_theta).reshape(B, 1, rd)
     if q_cache is not None:
         ckv_t, kr_t = q_cache(ckv_t, cfg), q_cache(kr_t, cfg)
-    rows = jnp.arange(B)
-    ckv = cache["ckv"].at[rows, pv].set(ckv_t[:, 0])
-    kr = cache["kr"].at[rows, pv].set(kr_t[:, 0])
+    sv = pos_vec(start, B)
+    ckv_b = append_row(buf["ckv"], ckv_t[:, 0], sv, pv)
+    kr_b = append_row(buf["kr"], kr_t[:, 0], sv, pv)
+    ckv = read_appended(history["ckv"], ckv_b, sv, pv)
+    kr = read_appended(history["kr"], kr_b, sv, pv)
     ckv_r = dq_cache(ckv) if dq_cache is not None else ckv
     kr_r = dq_cache(kr) if dq_cache is not None else kr
 
@@ -386,4 +436,4 @@ def decode_mla(p, x, cfg: ModelConfig, cache, pos, q_cache=None,
     wuv = p["wuv"].reshape(r, H, vd)
     out = jnp.einsum("bhr,rhv->bhv", out_lat, wuv.astype(jnp.float32))
     y = mm(out.reshape(B, 1, H * vd).astype(x.dtype), p["wo"])
-    return y, {"ckv": ckv, "kr": kr}
+    return y, {"ckv": ckv_b, "kr": kr_b}

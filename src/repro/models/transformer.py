@@ -52,6 +52,9 @@ __all__ = [
     "prefill",
     "prefill_into_slot",
     "decode_step",
+    "decode_step_buffered",
+    "init_append_buffer",
+    "commit_append_buffer",
 ]
 
 
@@ -428,27 +431,31 @@ def init_cache(cfg: ModelConfig, B: int, S: int, *, enc_len: int = 0,
     return stack(lambda: _layer_cache(cfg, B, S, enc_len))
 
 
-def _decode_layer(lp, x, cfg, cache, pos, *, is_local):
+def _decode_layer(lp, x, cfg, hist, buf, start, pos, *, is_local):
+    """One layer of a decode step: ``hist`` is the layer's read-only cache,
+    ``buf`` its append buffer (sequence leaves) and carried state (state
+    leaves); returns (x, updated buf)."""
     h = _rms(x, lp["ln1"])
     aout = jnp.zeros_like(x)
-    new_cache = dict(cache)
+    new_buf = dict(buf)
     if "attn" in lp:
         if cfg.attn_type == "mla":
             a, upd = attn.decode_mla(
                 lp["attn"], h, cfg,
-                {"ckv": cache["ckv"], "kr": cache["kr"]}, pos,
+                {"ckv": hist["ckv"], "kr": hist["kr"]},
+                {"ckv": buf["ckv"], "kr": buf["kr"]}, start, pos,
                 q_cache=_q_cache if cfg.kv_cache_dtype else None,
                 dq_cache=(lambda z: _dq_cache(z, cfg))
                 if cfg.kv_cache_dtype else None)
         else:
-            a, upd = _decode_gqa_at(lp["attn"], h, cfg, cache, pos,
-                                    is_local=is_local)
-        new_cache.update(upd)
+            a, upd = _decode_gqa_at(lp["attn"], h, cfg, hist, buf, start,
+                                    pos, is_local=is_local)
+        new_buf.update(upd)
         aout = aout + a
     if "ssm" in lp:
         s_out, s_state = ssm_mod.decode_ssm(lp["ssm"], h, cfg,
-                                            cache["ssm_state"])
-        new_cache["ssm_state"] = s_state
+                                            buf["ssm_state"])
+        new_buf["ssm_state"] = s_state
         aout = aout + s_out
         if "attn" in lp:
             aout = aout * 0.5
@@ -456,78 +463,153 @@ def _decode_layer(lp, x, cfg, cache, pos, *, is_local):
         aout = _rms(aout, lp["post_ln1"])
     x = x + aout
 
-    if "xattn" in lp and "xk" in cache:
+    if "xattn" in lp and "xk" in buf:
         hx = _rms(x, lp["lnx"])
-        x = x + _cross_attn_cached(lp["xattn"], hx, cache["xk"], cache["xv"],
+        x = x + _cross_attn_cached(lp["xattn"], hx, buf["xk"], buf["xv"],
                                    cfg)
 
     x, _ = _sublayer_ffn(lp, x, cfg)
-    return x, new_cache
+    return x, new_buf
 
 
-def _decode_gqa_at(p, x, cfg, cache, pos, *, is_local):
+def _is_ring(cfg, is_local, S_c) -> bool:
+    """A local layer whose cache is no longer than the window is a ring
+    buffer: position p lives at row p % S_c."""
+    return bool(is_local and cfg.local_window and S_c <= cfg.local_window)
+
+
+def _decode_gqa_at(p, x, cfg, hist, buf, start, pos, *, is_local):
     """GQA decode; local layers with a window-sized cache use it as a ring
-    buffer (write at pos % S_cache).  ``pos`` is a per-batch [B] vector —
-    slots in a continuous batch each write/attend at their own position."""
+    buffer (position p at row p % S_cache).  ``pos`` and ``start`` are
+    per-batch [B] vectors — slots in a continuous batch each write/attend
+    at their own position.  The new K/V row goes into the append buffer;
+    attention reads a copy of the layer's history with the buffer's
+    written rows scattered in (:func:`attention.read_appended`)."""
     B = x.shape[0]
-    pv = attn.pos_vec(pos, B)
-    q, k, v = attn._qkv(p, x, cfg, pv[:, None])
-    S_c = cache["k"].shape[1]
-    ring = bool(is_local and cfg.local_window and S_c <= cfg.local_window)
-    wpos = (pv % S_c) if ring else pv
-    rows = jnp.arange(B)
+    q, k, v = attn._qkv(p, x, cfg, pos[:, None])
+    S_c = hist["k"].shape[1]
+    ring = _is_ring(cfg, is_local, S_c)
     with jax.named_scope("kv.write"):
-        kc = cache["k"].at[rows, wpos].set(_q_cache(k[:, 0], cfg))
-        vc = cache["v"].at[rows, wpos].set(_q_cache(v[:, 0], cfg))
+        kb = attn.append_row(buf["k"], _q_cache(k[:, 0], cfg), start, pos)
+        vb = attn.append_row(buf["v"], _q_cache(v[:, 0], cfg), start, pos)
+        kc = attn.read_appended(hist["k"], kb, start, pos, ring=ring)
+        vc = attn.read_appended(hist["v"], vb, start, pos, ring=ring)
     with jax.named_scope("attn.decode"):
         kd, vd = _dq_cache(kc, cfg), _dq_cache(vc, cfg)
         if ring:
-            n_valid = jnp.minimum(pv + 1, S_c)
+            n_valid = jnp.minimum(pos + 1, S_c)
             out = attn.decode_attention(q, kd, vd, n_valid,
                                         softcap=cfg.attn_softcap)
         else:
             window = cfg.local_window if is_local else None
-            out = attn.decode_attention(q, kd, vd, pv + 1,
+            out = attn.decode_attention(q, kd, vd, pos + 1,
                                         softcap=cfg.attn_softcap,
                                         window=window)
     y = _mm(out.reshape(B, 1, -1), p["wo"])
-    return y, {"k": kc, "v": vc}
+    return y, {"k": kb, "v": vb}
+
+
+def _cache_kinds(cfg: ModelConfig, cache):
+    """:func:`_seq_leaf_kinds` for ``cache``, at the encoder length its
+    cross K/V leaves (if any) were built for."""
+    layer = cache["global"] if cfg.layer_pattern == "alt_local_global" \
+        else cache
+    return _seq_leaf_kinds(cfg, layer["xk"].shape[2] if "xk" in layer else 0)
+
+
+def init_append_buffer(cfg: ModelConfig, cache, n_steps: int):
+    """The append buffer for ``n_steps`` decode steps over ``cache``: each
+    sequence leaf [L, B, S, ...] becomes zeros [L, B, n_steps, ...] (row t
+    is position start + t); state leaves (SSM states, cross K/V) are the
+    cache's own, carried and updated step by step."""
+    return jax.tree_util.tree_map(
+        lambda l, is_seq: jnp.zeros(l.shape[:2] + (n_steps,) + l.shape[3:],
+                                    l.dtype) if is_seq else l,
+        cache, _cache_kinds(cfg, cache))
+
+
+def _commit_leaf(dst, rows, start, ring):
+    """Write rows [L, B, T, ...] of positions start .. start + T - 1 into
+    dst [L, B, S, ...], as :func:`attention.appended_rows` places them
+    after the chunk's last step."""
+    B, S, T = dst.shape[1], dst.shape[2], rows.shape[2]
+    w = attn.appended_rows(start, start + T - 1, T, S, ring=ring)
+    return dst.at[:, jnp.arange(B)[:, None], w].set(rows)
+
+
+@jax.named_scope("kv.commit")
+def commit_append_buffer(cfg: ModelConfig, cache, buf, start):
+    """Write a decode chunk's append buffer into the slot cache: each
+    slot's T rows at positions ``start .. start + T - 1`` (ring leaves at
+    p % S), and the carried state leaves wholesale."""
+    kinds = _cache_kinds(cfg, cache)
+    start = attn.pos_vec(start, jax.tree_util.tree_leaves(cache)[0].shape[1])
+
+    def commit(c, b, k, is_local):
+        return jax.tree_util.tree_map(
+            lambda cl, bl, is_seq: _commit_leaf(
+                cl, bl, start, _is_ring(cfg, is_local, cl.shape[2]))
+            if is_seq else bl, c, b, k)
+
+    if cfg.layer_pattern == "alt_local_global":
+        return {name: commit(cache[name], buf[name], kinds[name],
+                             name == "local")
+                for name in ("local", "global")}
+    return commit(cache, buf, kinds, cfg.layer_pattern == "local")
 
 
 @jax.named_scope("decode.step")
-def decode_step(params, cfg: ModelConfig, token, cache, pos):
-    """token [B, 1] int32; pos [] or [B] int32 (per-slot positions for the
-    continuous-batching engine); returns (logits [B, V], new cache).
+def decode_step_buffered(params, cfg: ModelConfig, token, history, buf,
+                         start, pos):
+    """One decode step over a read-only ``history`` cache and an append
+    buffer ``buf`` (:func:`init_append_buffer`) that holds the rows of
+    positions ``start .. pos - 1`` written by earlier steps of the chunk.
+    token [B, 1] int32; start, pos [] or [B] int32.  Returns (logits
+    [B, V], buf with this step's rows at ``pos - start`` and the new state
+    leaves).  The history is only read: the layer scan slices it, and its
+    only cache output is the updated buffer.
 
     Named scopes tag its device ops for a profiler trace: ``decode.step``
     the embedding, final norm and logits, ``decode.layers`` the layer
-    scan's own slicing of the stacked cache and restacking of the updated
-    one, ``decode.layer`` each layer's body."""
+    scan's slicing of the stacked history and buffer and restacking of
+    the buffer, ``decode.layer`` each layer's body."""
     x = jnp.take(params["embedding"], token, axis=0)
     x = x * jnp.asarray(jnp.sqrt(1.0 * cfg.d_model), x.dtype)
     x = logical_constraint(x, ("batch", None, None))
-    pos = attn.pos_vec(pos, token.shape[0])
+    B = token.shape[0]
+    pos, start = attn.pos_vec(pos, B), attn.pos_vec(start, B)
     pair = cfg.layer_pattern == "alt_local_global"
     all_local = cfg.layer_pattern == "local"
 
     @jax.named_scope("decode.layer")
-    def body(carry, xs):
-        h = carry
-        lp, c = xs
+    def body(h, xs):
+        lp, hc, bc = xs
         if pair:
-            h, cl = _decode_layer(lp["local"], h, cfg, c["local"], pos,
-                                  is_local=True)
-            h, cg = _decode_layer(lp["global"], h, cfg, c["global"], pos,
-                                  is_local=False)
-            return h, {"local": cl, "global": cg}
-        h, c2 = _decode_layer(lp, h, cfg, c, pos, is_local=all_local)
-        return h, c2
+            h, bl = _decode_layer(lp["local"], h, cfg, hc["local"],
+                                  bc["local"], start, pos, is_local=True)
+            h, bg = _decode_layer(lp["global"], h, cfg, hc["global"],
+                                  bc["global"], start, pos, is_local=False)
+            return h, {"local": bl, "global": bg}
+        return _decode_layer(lp, h, cfg, hc, bc, start, pos,
+                             is_local=all_local)
 
     with jax.named_scope("decode.layers"):
-        x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
+        x, buf = jax.lax.scan(body, x, (params["layers"], history, buf))
     x = _rms(x, params["final_norm"])
     logits = logits_of(params, cfg, x)[:, 0]
-    return logits, new_cache
+    return logits, buf
+
+
+def decode_step(params, cfg: ModelConfig, token, cache, pos):
+    """token [B, 1] int32; pos [] or [B] int32 (per-slot positions for the
+    continuous-batching engine); returns (logits [B, V], new cache): one
+    :func:`decode_step_buffered` step with a one-row buffer, then that row
+    committed at ``pos``."""
+    pos = attn.pos_vec(pos, token.shape[0])
+    logits, buf = decode_step_buffered(
+        params, cfg, token, cache, init_append_buffer(cfg, cache, 1), pos,
+        pos)
+    return logits, commit_append_buffer(cfg, cache, buf, pos)
 
 
 def _to_cache_dtype(piece, dst_dtype):

@@ -1,7 +1,7 @@
 """Serving KV caches: the static-shape state behind continuous batching.
 
 Two implementations share one contract (static shapes, per-slot positions,
-admission via prefill, decode via ``decode_step``):
+admission via prefill, decode via the model's decode core):
 
 * :class:`SlotKVCache` — the original slot-owns-a-full-row pool: one
   ``init_cache(cfg, max_slots, max_seq_len)`` pytree whose batch axis is a
@@ -16,11 +16,11 @@ admission via prefill, decode via ``decode_step``):
   stored as ``[L, num_pages, page_size, ...]`` and each slot owns an int32
   row of a ``[max_slots, pages_per_slot]`` page table mapping its logical
   pages to physical ones (sentinel ``num_pages`` = unmapped).  Decode
-  gathers a slot-major *view* through the table, runs the unchanged
-  ``decode_step`` on it, and commits only the newly written token rows
-  back through the table — so the XLA programs stay static-shape and the
-  attention/transformer entry points are untouched.  Requests admitted
-  with a common prompt prefix refcount the same physical pages
+  gathers a slot-major *view* through the table, runs the model's decode
+  core over it read-only with the call's new rows in a small append
+  buffer, and commits only those rows back through the table — so the
+  XLA programs stay static-shape and no loop carries the view.  Requests
+  admitted with a common prompt prefix refcount the same physical pages
   (copy-on-write; host bookkeeping in
   :class:`~repro.serve.queue.PageAllocator`), which is what lets a pool
   sized for N full sequences serve many times that many concurrent
@@ -162,12 +162,12 @@ def paged_view(cfg: ModelConfig, pool, table, page_size: int):
 
 
 @jax.named_scope("kv.commit")
-def paged_commit(cfg: ModelConfig, pool, view, table, pos, n_steps: int,
-                 page_size: int, num_pages: int):
-    """Write back what a decode chunk changed: for each slot, the
-    ``n_steps`` token rows written at positions ``pos .. pos+n_steps-1``
-    of the slot-major view are scattered into their physical pages; state
-    leaves are taken wholesale from the view.
+def paged_commit(cfg: ModelConfig, pool, buf, table, pos, page_size: int,
+                 num_pages: int):
+    """Write back what a decode chunk produced: each slot's append-buffer
+    rows (``buf`` sequence leaves [L, B, T, ...], positions ``pos ..
+    pos+T-1``) are scattered straight into their physical pages; state
+    leaves are taken wholesale from the buffer.
 
     Unmapped slots (sentinel table rows) and overshoot positions
     (``>= pages_per_slot * page_size``) resolve to the out-of-range page
@@ -178,21 +178,18 @@ def paged_commit(cfg: ModelConfig, pool, view, table, pos, n_steps: int,
     kinds = _seq_leaf_kinds(cfg, 0)
     B, pps = table.shape
     S = pps * page_size
-    t = jnp.arange(n_steps, dtype=jnp.int32)
-    wpos = pos[:, None] + t[None, :]                     # [B, T]
-    safe = jnp.clip(wpos, 0, S - 1)
-    phys = jnp.take_along_axis(table, safe // page_size, axis=1)
-    phys = jnp.where(wpos < S, phys, num_pages)          # drop overshoot
-    row = safe % page_size
-    bidx = jnp.arange(B, dtype=jnp.int32)[:, None]
 
-    def leaf(pl, vl, is_seq):
+    def leaf(pl, bl, is_seq):
         if not is_seq:
-            return vl
-        rows_v = vl[:, bidx, safe]                       # [L, B, T, ...]
-        return pl.at[:, phys, row].set(rows_v)
+            return bl
+        t = jnp.arange(bl.shape[2], dtype=jnp.int32)
+        wpos = pos[:, None] + t[None, :]                 # [B, T]
+        safe = jnp.clip(wpos, 0, S - 1)
+        phys = jnp.take_along_axis(table, safe // page_size, axis=1)
+        phys = jnp.where(wpos < S, phys, num_pages)      # drop overshoot
+        return pl.at[:, phys, safe % page_size].set(bl)
 
-    return jax.tree_util.tree_map(leaf, pool, view, kinds)
+    return jax.tree_util.tree_map(leaf, pool, buf, kinds)
 
 
 @functools.lru_cache(maxsize=16)

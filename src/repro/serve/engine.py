@@ -40,7 +40,8 @@ import numpy as np
 from repro.core.builder import SparsityBuilder
 from repro.core.layouts import GroupedNMTensor
 from repro.core.sparsifiers import GroupedNMSparsifier
-from repro.models import decode_step, init_cache, prefill
+from repro.models import commit_append_buffer, decode_step, \
+    decode_step_buffered, init_append_buffer, init_cache, prefill
 from repro.models.common import ModelConfig
 from repro.obs import trace as obs
 from repro.obs.registry import REGISTRY, MirroredCounters
@@ -87,24 +88,36 @@ def _decode_fn(cfg: ModelConfig):
     return step
 
 
+def _greedy_chunk(p, cfg: ModelConfig, tok, history, pos, n_steps: int):
+    """``n_steps`` greedy decode steps over a read-only ``history`` (the
+    slot cache, or the paged view) under one ``lax.scan``.  The scan
+    carries only the token, the chunk's append buffer and the position;
+    the history stays outside the carry.  Returns the [n_steps, B] tokens
+    and the buffer, for the caller to commit at ``pos``."""
+
+    def body(carry, _):
+        tok, buf, pv = carry
+        logits, buf = decode_step_buffered(p, cfg, tok, history, buf, pos,
+                                           pv)
+        nxt = jnp.argmax(logits, -1).astype(jnp.int32)   # [B] on device
+        return (nxt[:, None], buf, pv + 1), nxt
+
+    with jax.named_scope("decode.chunk"):
+        buf = init_append_buffer(cfg, history, n_steps)
+        (_, buf, _), toks = jax.lax.scan(
+            body, (tok, buf, pos), None, length=n_steps
+        )
+    return toks, buf
+
+
 def _decode_chunk_fn(cfg: ModelConfig, n_steps: int):
     """The raw chunked decode loop body (see :func:`_jit_decode_chunk`),
     split out for the same reason as :func:`_decode_fn`."""
 
     def chunk(p, tok, cache, pos):
         note_trace("decode_chunk")  # trace-time only: counts compilations
-
-        def body(carry, _):
-            tok, cache, pos = carry
-            logits, cache = decode_step(p, cfg, tok, cache, pos)
-            nxt = jnp.argmax(logits, -1).astype(jnp.int32)   # [B] on device
-            return (nxt[:, None], cache, pos + 1), nxt
-
-        with jax.named_scope("decode.chunk"):
-            (_, cache, _), toks = jax.lax.scan(
-                body, (tok, cache, pos), None, length=n_steps
-            )
-        return toks, cache
+        toks, buf = _greedy_chunk(p, cfg, tok, cache, pos, n_steps)
+        return toks, commit_append_buffer(cfg, cache, buf, pos)
 
     return chunk
 
@@ -158,16 +171,17 @@ def serve_programs(params, cfg: ModelConfig, *, max_slots: int = 4,
 @functools.lru_cache(maxsize=_JIT_CACHE_SIZE)
 def _jit_paged_decode(cfg: ModelConfig, page_size: int, num_pages: int):
     """Paged analogue of :func:`_jit_decode`: gather the slot-major
-    logical cache out of the page pool through the table, run the
-    *unchanged* ``decode_step`` on it, and commit only the one written
-    token row per slot back to its physical page.  The pool is donated —
-    the gather/commit pair updates it in place."""
+    logical cache out of the page pool through the table, run one decode
+    step over it read-only with a one-row append buffer, and commit that
+    row per slot to its physical page.  The pool is donated — the commit
+    updates it in place."""
 
     def step(p, tok, pool, table, pos):
         note_trace("paged_decode")  # trace-time only: counts compilations
         view = paged_view(cfg, pool, table, page_size)
-        logits, view = decode_step(p, cfg, tok, view, pos)
-        pool = paged_commit(cfg, pool, view, table, pos, 1, page_size,
+        logits, buf = decode_step_buffered(
+            p, cfg, tok, view, init_append_buffer(cfg, view, 1), pos, pos)
+        pool = paged_commit(cfg, pool, buf, table, pos, page_size,
                             num_pages)
         return logits, pool
 
@@ -178,29 +192,20 @@ def _jit_paged_decode(cfg: ModelConfig, page_size: int, num_pages: int):
 def _jit_paged_decode_chunk(cfg: ModelConfig, page_size: int,
                             num_pages: int, n_steps: int):
     """Paged analogue of :func:`_jit_decode_chunk`: one gather, ``n_steps``
-    decode steps over the slot-major view under ``lax.scan`` (the exact
-    loop the slot cache runs, so greedy tokens match it bitwise), then one
-    commit of the ``n_steps`` written rows per slot.  The engine
-    guarantees (via ``ensure_writable_range``) that every mapped page in
-    the write range is private before this runs; unmapped/overshoot
-    destinations resolve to the sentinel page and are dropped."""
+    decode steps over the read-only slot-major view with the new rows in
+    an append buffer (the exact loop the slot cache runs, so greedy tokens
+    match it bitwise), then one commit of the buffer's ``n_steps`` rows
+    per slot.  The engine guarantees (via ``ensure_writable_range``) that
+    every mapped page in the write range is private before this runs;
+    unmapped/overshoot destinations resolve to the sentinel page and are
+    dropped."""
 
     def chunk(p, tok, pool, table, pos):
         note_trace("paged_decode_chunk")  # trace-time: counts compilations
         view = paged_view(cfg, pool, table, page_size)
-
-        def body(carry, _):
-            tok, view, pv = carry
-            logits, view = decode_step(p, cfg, tok, view, pv)
-            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            return (nxt[:, None], view, pv + 1), nxt
-
-        with jax.named_scope("decode.chunk"):
-            (_, view, _), toks = jax.lax.scan(
-                body, (tok, view, pos), None, length=n_steps
-            )
-        pool = paged_commit(cfg, pool, view, table, pos, n_steps,
-                            page_size, num_pages)
+        toks, buf = _greedy_chunk(p, cfg, tok, view, pos, n_steps)
+        pool = paged_commit(cfg, pool, buf, table, pos, page_size,
+                            num_pages)
         return toks, pool
 
     return jax.jit(chunk, donate_argnums=(2,))
@@ -269,8 +274,9 @@ class ServeEngine:
         (host-side RNG sampling keeps per-request streams batch-independent).
     clock : timestamp source (injectable for deterministic tests)
     paged : back the KV cache with :class:`PagedKVCache` instead of
-        :class:`SlotKVCache`.  Decode runs the same ``decode_step`` over a
-        gathered slot-major view of the page pool, so outputs match the
+        :class:`SlotKVCache`.  Decode runs the same decode core as the
+        slot cache over a gathered slot-major view of the page pool (read
+        only; the new rows go into an append buffer), so outputs match the
         slot cache token-for-token; what changes is capacity — with
         ``num_pages`` oversubscribed relative to
         ``max_slots * max_seq_len / page_size``, short prompts and shared
@@ -370,14 +376,17 @@ class ServeEngine:
         #: scheduler counters (all zero for the slot cache except
         #: rejected/peak_active): deferred admissions, mid-stream
         #: preemptions, rejected requests, peak concurrently-active slots,
-        #: plus the SLO/fault loop's shed/timeout/retry/tier-switch counts.
+        #: plus the SLO/fault loop's shed/timeout/retry/tier-switch counts,
+        #: and the decode steps run by the chunk program (append buffer
+        #: over a read-only cache) against those on the one-step fallback.
         #: Reads/writes behave exactly like the plain dict this used to
         #: be; increases additionally mirror into the telemetry registry
         #: so a benchmark's registry snapshot includes engine stats.
         self.stats = MirroredCounters(
             {"deferred_admissions": 0, "preemptions": 0,
              "rejected": 0, "peak_active": 0, "shed": 0,
-             "timeout": 0, "fault_retries": 0, "tier_switches": 0},
+             "timeout": 0, "fault_retries": 0, "tier_switches": 0,
+             "decode_steps_chunked": 0, "decode_steps_single": 0},
             REGISTRY.family("engine_stats",
                             help="engine scheduler counters"))
         # chunked decode falls back to single-step once a lone slot cannot
@@ -801,6 +810,8 @@ class ServeEngine:
             t0 = self._now()
             out, self.kv.data = fn(self.params, tok, self.kv.data, *table,
                                    pos)
+            self.stats["decode_steps_chunked" if T > 1
+                       else "decode_steps_single"] += T
             with obs.span("engine.decode.fetch", "engine"):
                 # [T, max_slots] tokens or [max_slots, V] logits — one sync
                 out = np.asarray(out)

@@ -8,7 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.models.attention import chunked_attention, decode_attention
+from repro.models.attention import (append_row, chunked_attention,
+                                    decode_attention, read_appended)
+from repro.models.transformer import _commit_leaf
 
 B, S, H, KV, hd = 2, 64, 4, 2, 16
 KEY = jax.random.PRNGKey(0)
@@ -104,3 +106,65 @@ def test_decode_matches_last_row_of_naive():
     want = naive(Q, K, V)[:, -1:]
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want), rtol=2e-3, atol=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# append buffer: read-only history + a chunk's new rows
+# ---------------------------------------------------------------------------
+
+
+def _written(history, rows, start, n, ring):
+    """The reference: write rows 0..n-1 (positions start + t) into the
+    history one by one, as a per-row decode step would (ring rows at
+    p % S; positions past S dropped)."""
+    h = np.array(history)
+    S = h.shape[1]
+    for b in range(h.shape[0]):
+        for t in range(n):
+            p = int(start[b]) + t
+            if ring:
+                h[b, p % S] = rows[b, t]
+            elif p < S:
+                h[b, p] = rows[b, t]
+    return h
+
+
+@pytest.mark.parametrize("ring", [False, True], ids=["linear", "ring"])
+@pytest.mark.parametrize("T", [1, 3, 6])
+def test_read_appended_equals_sequential_writes(ring, T):
+    """After each step of a chunk, the history read through the append
+    buffer is bitwise the history that writing each step's row in place
+    would hold — across slots at different positions, a chunk that runs
+    past the end of a linear history, and a ring shorter than the chunk
+    (a row written twice keeps the later position)."""
+    B, S = 3, 4
+    rng = np.random.default_rng(T)
+    history = jnp.asarray(rng.standard_normal((B, S, 2, 3)), jnp.float32)
+    rows = jnp.asarray(rng.standard_normal((B, T, 2, 3)), jnp.float32)
+    start = jnp.asarray([0, 2, 3], jnp.int32)
+    buf = jnp.zeros((B, T, 2, 3), jnp.float32)
+    for t in range(T):
+        pos = start + t
+        buf = append_row(buf, rows[:, t], start, pos)
+        got = read_appended(history, buf, start, pos, ring=ring)
+        want = _written(history, np.asarray(rows), np.asarray(start), t + 1,
+                        ring)
+        np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(np.asarray(buf), np.asarray(rows))
+
+
+@pytest.mark.parametrize("ring", [False, True], ids=["linear", "ring"])
+@pytest.mark.parametrize("T", [1, 3, 6])
+def test_commit_leaf_equals_sequential_writes(ring, T):
+    """Committing a chunk's buffer [L, B, T, ...] into a stacked cache
+    [L, B, S, ...] leaves what the chunk's per-row writes would have."""
+    L, B, S = 2, 3, 4
+    rng = np.random.default_rng(10 + T)
+    cache = jnp.asarray(rng.standard_normal((L, B, S, 5)), jnp.float32)
+    rows = jnp.asarray(rng.standard_normal((L, B, T, 5)), jnp.float32)
+    start = jnp.asarray([0, 2, 3], jnp.int32)
+    got = np.asarray(_commit_leaf(cache, rows, start, ring))
+    for layer in range(L):
+        want = _written(cache[layer], np.asarray(rows[layer]),
+                        np.asarray(start), T, ring)
+        np.testing.assert_array_equal(got[layer], want)

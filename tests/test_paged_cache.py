@@ -4,9 +4,10 @@ randomized fallbacks), copy-on-write prefix sharing, and compaction.
 
 The load-bearing guarantee: the paged engine is *observationally
 identical* to the slot engine — same tokens for every request under any
-admission/eviction order — because decode runs the unchanged
-``decode_step`` over a gathered slot-major view of the page pool.  These
-tests pin that down at both the cache layer (bitwise KV rows) and the
+admission/eviction order — because decode runs the same decode core
+(``decode_step_buffered``: a read-only history plus an append buffer of
+the call's new rows) over a gathered slot-major view of the page pool.
+These tests pin that down at both the cache layer (bitwise KV rows) and the
 engine layer (token streams under oversubscription, sharing, preemption).
 """
 
@@ -29,7 +30,9 @@ from repro.serve import (
     SlotKVCache,
     prefix_hashes,
 )
-from repro.serve.engine import _jit_decode, _jit_paged_decode
+from repro.models.transformer import _seq_leaf_kinds
+from repro.serve.engine import _jit_decode, _jit_paged_decode, \
+    _jit_paged_decode_chunk
 
 KEY = jax.random.PRNGKey(0)
 
@@ -180,6 +183,118 @@ def test_paged_kv_bitwise_equals_slot(setup, page_size):
         for a, b in zip(seq_rows(sk.data, slot, valid),
                         seq_rows(view, slot, valid)):
             np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("page_size", [4, 8])
+def test_paged_chunk_kv_bitwise_equals_single_step(setup, page_size):
+    """A chunk of 4 (append buffer over the read-only view, one commit)
+    leaves the pool, read back through the table, bitwise what 4 calls of
+    the single-step program write, and samples the same tokens."""
+    cfg, params = setup
+    S0, S1, T = 11, 6, 4
+    p0 = jnp.asarray(make_prompt(S0, seed=1, vocab=cfg.vocab)[None])
+    p1 = jnp.asarray(make_prompt(S1, seed=2, vocab=cfg.vocab)[None])
+    chunked = PagedKVCache(cfg, 2, 32, page_size=page_size)
+    single = PagedKVCache(cfg, 2, 32, page_size=page_size)
+    tok = []
+    for kv in (chunked, single):
+        tok = [int(jnp.argmax(kv.admit(params, p, slot)[0]))
+               for slot, p in enumerate((p0, p1))]
+    pos = np.asarray([S0, S1], np.int32)
+    for kv in (chunked, single):
+        for slot in (0, 1):
+            assert kv.ensure_writable_range(slot, int(pos[slot]), T)
+
+    chunk = _jit_paged_decode_chunk(cfg, page_size, chunked.num_pages, T)
+    toks, chunked.data = chunk(params, jnp.asarray(tok, jnp.int32)[:, None],
+                               chunked.data, chunked.device_table(),
+                               jnp.asarray(pos))
+    step = _jit_paged_decode(cfg, page_size, single.num_pages)
+    t = np.asarray(tok, np.int32)
+    for i in range(T):
+        logits, single.data = step(params, jnp.asarray(t[:, None]),
+                                   single.data, single.device_table(),
+                                   jnp.asarray(pos + i))
+        t = np.asarray(jnp.argmax(logits, -1), np.int32)
+        np.testing.assert_array_equal(np.asarray(toks[i]), t)
+
+    for slot, valid in ((0, S0 + T), (1, S1 + T)):
+        for a, b in zip(seq_rows(chunked.logical_view(), slot, valid),
+                        seq_rows(single.logical_view(), slot, valid)):
+            np.testing.assert_array_equal(a, b)
+
+
+def _scans(jaxpr):
+    """Every ``scan`` equation in a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _scans(sub)
+
+
+def test_paged_chunk_scan_carries_no_view(setup):
+    """The paged chunk program moves no copy of the gathered view through
+    its loops: no carry of the chunk scan, and no output of the layer
+    scan, has the shape of a sequence leaf of the view [L, B, S, ...].
+    The chunk scan carries the [L, B, T, ...] append buffer instead."""
+    cfg, params = setup
+    T = 4
+    kv = PagedKVCache(cfg, 2, 32, page_size=8)
+    chunk = _jit_paged_decode_chunk(cfg, 8, kv.num_pages, T)
+    jaxpr = jax.make_jaxpr(chunk)(
+        params, jnp.zeros((2, 1), jnp.int32), kv.data, kv.device_table(),
+        jnp.zeros(2, jnp.int32))
+    view = jax.eval_shape(kv.logical_view)
+    seq = {leaf.shape for leaf, is_seq in zip(
+        jax.tree_util.tree_leaves(view),
+        jax.tree_util.tree_leaves(_seq_leaf_kinds(cfg, 0))) if is_seq}
+    assert seq
+    buffer = {s[:2] + (T,) + s[3:] for s in seq}
+
+    scans = list(_scans(jaxpr.jaxpr))
+    chunk_scans = [e for e in scans if e.params["length"] == T]
+    layer_scans = [e for e in scans if e.params["length"] == cfg.n_layers]
+    assert len(chunk_scans) == 1 and layer_scans
+    (loop,) = chunk_scans
+    n0, nc = loop.params["num_consts"], loop.params["num_carry"]
+    carry = {v.aval.shape for v in loop.invars[n0:n0 + nc]}
+    assert not carry & seq, carry
+    assert buffer <= carry, carry
+    for layers in layer_scans:
+        ys = {v.aval.shape for v in layers.outvars[layers.params["num_carry"]:]}
+        assert not ys & seq, ys
+
+
+FAMILIES = ["minicpm3-4b", "hymba-1.5b", "gemma2-9b", "mamba2-370m"]
+
+
+@pytest.mark.parametrize("arch", FAMILIES)
+def test_paged_and_chunked_match_per_token_across_families(arch):
+    """MLA latents, hybrid attention + SSM state, local/global pairs with
+    window-sized (ring) caches, and a pure state model all take the same
+    append-buffer path: the slot and paged engines, chunked or not, give
+    the per-token slot loop's tokens."""
+    cfg = dataclasses.replace(get_smoke(arch), dtype="float32")
+    params = init_lm(jax.random.PRNGKey(3), cfg)
+
+    def trace():
+        return [Request(uid=i, prompt=make_prompt(L, seed=90 + i,
+                                                  vocab=cfg.vocab),
+                        max_new_tokens=4 + 2 * (i % 3))
+                for i, L in enumerate([3, 8, 5, 6])]
+
+    kw = dict(max_slots=2, max_seq_len=16)
+    want = run_tokens(ServeEngine(params, cfg, decode_chunk=1, **kw),
+                      trace())
+    for extra in (dict(decode_chunk=4),
+                  dict(decode_chunk=4, paged=True, page_size=4),
+                  dict(decode_chunk=1, paged=True, page_size=8)):
+        got = run_tokens(ServeEngine(params, cfg, **kw, **extra), trace())
+        assert got == want, extra
 
 
 def test_admission_order_does_not_leak_between_slots(setup):

@@ -142,6 +142,46 @@ def test_non_greedy_requests_take_host_path(setup):
     assert [o.tokens for o in a] == [o.tokens for o in b]
 
 
+@pytest.mark.parametrize("paged", [False, True], ids=["slot", "paged"])
+def test_decode_step_counters_split_chunked_from_single(setup, paged):
+    """``decode_steps_chunked`` counts the steps of the append-buffer chunk
+    program, ``decode_steps_single`` those of the one-step fallback, and
+    both mirror into the ``engine_stats`` registry family.  A greedy run
+    only chunks; a non-greedy request forces one-step decode while it is
+    active, then the greedy one left over chunks again."""
+    from repro.obs.registry import REGISTRY
+
+    cfg, params = setup
+    fam = REGISTRY.family("engine_stats")
+    kw = dict(max_slots=2, max_seq_len=32, decode_chunk=4, paged=paged,
+              page_size=8)
+    sp = SamplingParams(greedy=False, temperature=0.7, top_k=8, seed=7)
+
+    before = dict(fam)
+    greedy = ServeEngine(params, cfg, **kw)
+    greedy.run([Request(uid=0, prompt=make_prompt(6, seed=40,
+                                                  vocab=cfg.vocab),
+                        max_new_tokens=9)])
+    # the admission samples token 1; two chunks of 4 sample the other 8
+    assert greedy.stats["decode_steps_chunked"] == 8
+    assert greedy.stats["decode_steps_single"] == 0
+
+    mixed = ServeEngine(params, cfg, **kw)
+    mixed.run([Request(uid=0, prompt=make_prompt(6, seed=41,
+                                                 vocab=cfg.vocab),
+                       max_new_tokens=5, sampling=sp),
+               Request(uid=1, prompt=make_prompt(7, seed=42,
+                                                 vocab=cfg.vocab),
+                       max_new_tokens=13)])
+    # 4 one-step calls while the sampled request is active, then the
+    # greedy one's last 8 tokens in two chunks
+    assert mixed.stats["decode_steps_single"] == 4
+    assert mixed.stats["decode_steps_chunked"] == 8
+    for key in ("decode_steps_chunked", "decode_steps_single"):
+        assert fam[key] - before.get(key, 0) == (greedy.stats[key]
+                                                 + mixed.stats[key])
+
+
 # ---------------------------------------------------------------------------
 # scheduling: admission, eviction, mid-stream arrival
 # ---------------------------------------------------------------------------
