@@ -26,6 +26,7 @@ __all__ = [
     "init_gqa",
     "apply_gqa",
     "decode_gqa",
+    "out_proj",
     "init_mla",
     "apply_mla",
     "decode_mla",
@@ -272,6 +273,8 @@ def init_gqa(key, cfg: ModelConfig):
         p["bq"] = jnp.zeros((H * hd,), cfg.jdtype)
         p["bk"] = jnp.zeros((KV * hd,), cfg.jdtype)
         p["bv"] = jnp.zeros((KV * hd,), cfg.jdtype)
+    if cfg.proj_bias:
+        p["bo"] = jnp.zeros((D,), cfg.jdtype)
     return p
 
 
@@ -292,6 +295,13 @@ def _qkv(p, x, cfg: ModelConfig, positions):
     return q, k, v
 
 
+def out_proj(p, a):
+    """The output projection ``wo``, with its bias ``bo`` where the
+    configuration has one (``proj_bias``)."""
+    y = mm(a, p["wo"])
+    return y + p["bo"] if "bo" in p else y
+
+
 def apply_gqa(p, x, cfg: ModelConfig, *, is_local=False, prefix_len=0,
               positions=None, causal=True):
     B, S, D = x.shape
@@ -304,7 +314,7 @@ def apply_gqa(p, x, cfg: ModelConfig, *, is_local=False, prefix_len=0,
         softcap=cfg.attn_softcap, chunk_q=cfg.attn_chunk_q,
         chunk_k=cfg.attn_chunk_k, compute_dtype=cfg.attn_dtype,
     )
-    return mm(out.reshape(B, S, -1), p["wo"]), (k, v)
+    return out_proj(p, out.reshape(B, S, -1)), (k, v)
 
 
 def decode_gqa(p, x, cfg: ModelConfig, cache, pos, *, is_local=False):
@@ -318,8 +328,7 @@ def decode_gqa(p, x, cfg: ModelConfig, cache, pos, *, is_local=False):
     window = cfg.local_window if is_local else None
     out = decode_attention(q, kc, vc, pv + 1, softcap=cfg.attn_softcap,
                            window=window)
-    y = mm(out.reshape(B, 1, -1), p["wo"])
-    return y, {"k": kc, "v": vc}
+    return out_proj(p, out.reshape(B, 1, -1)), {"k": kc, "v": vc}
 
 
 # ---------------------------------------------------------------------------

@@ -153,15 +153,20 @@ class ModelConfig:
     # attention family
     attn_type: str = "gqa"        # gqa | mla | none (pure SSM) | hybrid
     qkv_bias: bool = False        # Qwen-style
+    proj_bias: bool = False       # biases on attn.wo, mlp.wi, mlp.wo (non-gated)
     logit_softcap: Optional[float] = None      # Gemma2 final-logit softcap
     attn_softcap: Optional[float] = None       # Gemma2 attention softcap
     local_window: Optional[int] = None         # sliding-window size
     layer_pattern: str = "global"  # global | local | alt_local_global
     post_norms: bool = False       # Gemma2 pre+post block norms
+    # rms: x / rms(x) * (1 + w), eps 1e-6;  layer: LayerNorm with weight
+    # and bias (x - mean) / std * w + b, eps 1e-5 (StarCoder2)
+    norm_type: str = "rms"
     act: str = "silu"              # silu | gelu
     gated_mlp: bool = True
     rope_theta: float = 10000.0
     tie_embeddings: bool = False
+    embed_scale: bool = True       # embeddings times sqrt(d_model)
     # sub-family configs
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
@@ -195,6 +200,9 @@ class ModelConfig:
 
     def validate(self):
         assert self.n_heads % max(1, self.n_kv_heads) == 0
+        assert self.norm_type in ("rms", "layer")
+        assert not (self.proj_bias and self.gated_mlp), \
+            "MLP biases are implemented for the non-gated MLP only"
         if self.attn_type == "mla":
             assert self.mla is not None
         if self.attn_type in ("none", "hybrid"):

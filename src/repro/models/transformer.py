@@ -28,6 +28,7 @@ the layer scan; ``decode_step`` is the one-token path over that cache.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Optional
 
@@ -68,6 +69,36 @@ def _rms(x, w, eps: float = 1e-6):
     return (y * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
 
 
+def _layer_norm(x, w, b, eps: float = 1e-5):
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
+    y = (xf - mean) * jax.lax.rsqrt(var + eps)
+    return (y * w.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
+
+
+def _norm(p, name: str, x, cfg: ModelConfig):
+    """The block norm ``p[name]``: RMS (scale ``1 + w``), or for
+    ``norm_type="layer"`` LayerNorm with weight ``p[name]`` and bias
+    ``p[name + "_b"]``."""
+    if cfg.norm_type == "layer":
+        return _layer_norm(x, p[name], p[name + "_b"])
+    return _rms(x, p[name])
+
+
+def _init_norm(p: dict, name: str, cfg: ModelConfig) -> None:
+    """Identity-initialized parameters of the norm ``name`` into ``p``."""
+    if cfg.norm_type == "layer":
+        p[name] = jnp.ones((cfg.d_model,), cfg.jdtype)
+        p[name + "_b"] = jnp.zeros((cfg.d_model,), cfg.jdtype)
+    else:
+        p[name] = jnp.zeros((cfg.d_model,), cfg.jdtype)
+
+
+def _bias(y, b):
+    return y if b is None else y + b
+
+
 def _act(name):
     return jax.nn.silu if name == "silu" else functools.partial(
         jax.nn.gelu, approximate=True
@@ -84,13 +115,18 @@ def _init_mlp(key, cfg: ModelConfig):
     k1, k2 = jax.random.split(key)
     wi = dense_init(k1, (D, 2 * F if cfg.gated_mlp else F), cfg.jdtype)
     wo = dense_init(k2, (F, D), cfg.jdtype)
-    return {"wi": wi, "wo": wo}
+    p = {"wi": wi, "wo": wo}
+    if cfg.proj_bias:
+        p["bi"] = jnp.zeros((F,), cfg.jdtype)
+        p["bo"] = jnp.zeros((D,), cfg.jdtype)
+    return p
 
 
 def _init_layer(key, cfg: ModelConfig, cross: bool = False):
     ks = jax.random.split(key, 6)
-    p: dict[str, Any] = {"ln1": jnp.zeros((cfg.d_model,), cfg.jdtype),
-                         "ln2": jnp.zeros((cfg.d_model,), cfg.jdtype)}
+    p: dict[str, Any] = {}
+    _init_norm(p, "ln1", cfg)
+    _init_norm(p, "ln2", cfg)
     if cfg.attn_type in ("gqa", "hybrid"):
         p["attn"] = attn.init_gqa(ks[0], cfg)
     elif cfg.attn_type == "mla":
@@ -116,8 +152,8 @@ def init_lm(key, cfg: ModelConfig):
     params: dict[str, Any] = {
         "embedding": dense_init(k_emb, (cfg.vocab, cfg.d_model), cfg.jdtype,
                                 scale=1.0),
-        "final_norm": jnp.zeros((cfg.d_model,), cfg.jdtype),
     }
+    _init_norm(params, "final_norm", cfg)
     pair = cfg.layer_pattern == "alt_local_global"
     n_bodies = cfg.n_layers // 2 if pair else cfg.n_layers
     cross = cfg.n_enc_layers > 0
@@ -150,7 +186,7 @@ def init_lm(key, cfg: ModelConfig):
 
 def _sublayer_attn(lp, x, cfg, *, is_local, prefix_len, causal,
                    enc_out=None, collect=False):
-    h = _rms(x, lp["ln1"])
+    h = _norm(lp, "ln1", x, cfg)
     aout = jnp.zeros_like(x)
     contrib: dict[str, Any] = {}
     if "attn" in lp:
@@ -210,7 +246,7 @@ def _cross_attn_cached(p, x, xk, xv, cfg):
 
 
 def _sublayer_ffn(lp, x, cfg):
-    h = _rms(x, lp["ln2"])
+    h = _norm(lp, "ln2", x, cfg)
     aux = jnp.zeros((), jnp.float32)
     if "moe" in lp:
         if cfg.moe.impl == "shmap":
@@ -218,6 +254,7 @@ def _sublayer_ffn(lp, x, cfg):
         else:
             f, aux = moe_mod.apply_moe(lp["moe"], h, cfg)
     elif "mlp" in lp:
+        mlp = lp["mlp"]
         inline = None
         if cfg.mlp_inline_threshold is not None:
             from repro.core.sparsifiers import ScalarThresholdSparsifier
@@ -226,16 +263,16 @@ def _sublayer_ffn(lp, x, cfg):
             # fused gated megakernel: projection + split + act + gate in
             # one decode launch when eligible; None -> sequential path
             # (bitwise-equal — the kernel epilogue replays these exact ops)
-            hh = mm_gated(h, lp["mlp"]["wi"], cfg.act, inline=inline)
+            hh = mm_gated(h, mlp["wi"], cfg.act, inline=inline)
             if hh is None:
-                hh = _mm(h, lp["mlp"]["wi"], inline=inline)
+                hh = _mm(h, mlp["wi"], inline=inline)
                 u, v = jnp.split(hh, 2, axis=-1)
                 hh = _act(cfg.act)(u) * v
         else:
-            hh = _mm(h, lp["mlp"]["wi"], inline=inline)
+            hh = _bias(_mm(h, mlp["wi"], inline=inline), mlp.get("bi"))
             hh = _act(cfg.act)(hh)
         hh = tag("mlp.act", hh)
-        f = _mm(hh, lp["mlp"]["wo"])
+        f = _bias(_mm(hh, mlp["wo"]), mlp.get("bo"))
     else:
         return x, aux
     f = tag("mlp.out", f)
@@ -244,12 +281,20 @@ def _sublayer_ffn(lp, x, cfg):
     return x + f, aux
 
 
+def _scope(name: str, on: bool):
+    return jax.named_scope(name) if on else contextlib.nullcontext()
+
+
 def _layer(lp, x, cfg, *, is_local, prefix_len, causal, enc_out=None,
            collect=False):
-    x, contrib = _sublayer_attn(lp, x, cfg, is_local=is_local,
-                                prefix_len=prefix_len, causal=causal,
-                                enc_out=enc_out, collect=collect)
-    x, aux = _sublayer_ffn(lp, x, cfg)
+    """One block; a cache-collecting (prefill) forward names its two
+    sublayers ``repro.prefill.attn`` and ``repro.prefill.mlp``."""
+    with _scope("repro.prefill.attn", collect):
+        x, contrib = _sublayer_attn(lp, x, cfg, is_local=is_local,
+                                    prefix_len=prefix_len, causal=causal,
+                                    enc_out=enc_out, collect=collect)
+    with _scope("repro.prefill.mlp", collect):
+        x, aux = _sublayer_ffn(lp, x, cfg)
     return x, aux, contrib
 
 
@@ -285,6 +330,13 @@ def _run_encoder(params, cfg, enc_embeds, dtype, remat="none"):
     return _rms(e, params["enc_norm"])
 
 
+def _embed(params, cfg: ModelConfig, tokens):
+    x = jnp.take(params["embedding"], tokens, axis=0)
+    if cfg.embed_scale:
+        x = x * jnp.asarray(jnp.sqrt(1.0 * cfg.d_model), x.dtype)
+    return x
+
+
 def forward(params, cfg: ModelConfig, tokens=None, *, embeds=None,
             enc_embeds=None, prefix_embeds=None, remat: str = "full",
             collect_cache: bool = False):
@@ -295,10 +347,7 @@ def forward(params, cfg: ModelConfig, tokens=None, *, embeds=None,
     cross-attention.  With ``collect_cache`` the per-layer decode-cache
     contributions are returned stacked on a leading layer axis."""
     if embeds is None:
-        embeds = jnp.take(params["embedding"], tokens, axis=0)
-        embeds = embeds * jnp.asarray(
-            jnp.sqrt(1.0 * cfg.d_model), embeds.dtype
-        )
+        embeds = _embed(params, cfg, tokens)
     prefix_len = 0
     if prefix_embeds is not None:
         embeds = jnp.concatenate([prefix_embeds.astype(embeds.dtype), embeds],
@@ -318,7 +367,7 @@ def forward(params, cfg: ModelConfig, tokens=None, *, embeds=None,
     (x, aux), contribs = jax.lax.scan(
         body, (x, jnp.zeros((), jnp.float32)), params["layers"]
     )
-    x = _rms(x, params["final_norm"])
+    x = _norm(params, "final_norm", x, cfg)
     if collect_cache:
         return x, aux, contribs, enc_out
     return x, aux
@@ -434,8 +483,9 @@ def init_cache(cfg: ModelConfig, B: int, S: int, *, enc_len: int = 0,
 def _decode_layer(lp, x, cfg, hist, buf, start, pos, *, is_local):
     """One layer of a decode step: ``hist`` is the layer's read-only cache,
     ``buf`` its append buffer (sequence leaves) and carried state (state
-    leaves); returns (x, updated buf)."""
-    h = _rms(x, lp["ln1"])
+    leaves); returns (x, updated buf).  The MLP is scoped
+    ``repro.decode.mlp``."""
+    h = _norm(lp, "ln1", x, cfg)
     aout = jnp.zeros_like(x)
     new_buf = dict(buf)
     if "attn" in lp:
@@ -468,7 +518,8 @@ def _decode_layer(lp, x, cfg, hist, buf, start, pos, *, is_local):
         x = x + _cross_attn_cached(lp["xattn"], hx, buf["xk"], buf["xv"],
                                    cfg)
 
-    x, _ = _sublayer_ffn(lp, x, cfg)
+    with jax.named_scope("repro.decode.mlp"):
+        x, _ = _sublayer_ffn(lp, x, cfg)
     return x, new_buf
 
 
@@ -505,8 +556,7 @@ def _decode_gqa_at(p, x, cfg, hist, buf, start, pos, *, is_local):
             out = attn.decode_attention(q, kd, vd, pos + 1,
                                         softcap=cfg.attn_softcap,
                                         window=window)
-    y = _mm(out.reshape(B, 1, -1), p["wo"])
-    return y, {"k": kb, "v": vb}
+    return attn.out_proj(p, out.reshape(B, 1, -1)), {"k": kb, "v": vb}
 
 
 def _cache_kinds(cfg: ModelConfig, cache):
@@ -573,9 +623,7 @@ def decode_step_buffered(params, cfg: ModelConfig, token, history, buf,
     the embedding, final norm and logits, ``decode.layers`` the layer
     scan's slicing of the stacked history and buffer and restacking of
     the buffer, ``decode.layer`` each layer's body."""
-    x = jnp.take(params["embedding"], token, axis=0)
-    x = x * jnp.asarray(jnp.sqrt(1.0 * cfg.d_model), x.dtype)
-    x = logical_constraint(x, ("batch", None, None))
+    x = logical_constraint(_embed(params, cfg, token), ("batch", None, None))
     B = token.shape[0]
     pos, start = attn.pos_vec(pos, B), attn.pos_vec(start, B)
     pair = cfg.layer_pattern == "alt_local_global"
@@ -595,7 +643,7 @@ def decode_step_buffered(params, cfg: ModelConfig, token, history, buf,
 
     with jax.named_scope("decode.layers"):
         x, buf = jax.lax.scan(body, x, (params["layers"], history, buf))
-    x = _rms(x, params["final_norm"])
+    x = _norm(params, "final_norm", x, cfg)
     logits = logits_of(params, cfg, x)[:, 0]
     return logits, buf
 

@@ -58,7 +58,7 @@ from repro.serve.slo import LatencyModel, SLOConfig, SLOController, \
 from repro.serve.tracecount import note_trace
 
 __all__ = ["ServeEngine", "sparsify_for_serving", "compare_dense_sparse",
-           "warmup_engine", "serve_programs"]
+           "warmup_engine", "serve_programs", "window_masked_rows"]
 
 
 #: bound on the per-config jitted-closure caches below.  Each entry pins a
@@ -229,7 +229,11 @@ def sparsify_for_serving(params, n: int = 1, m: int = 4, g: int = 16,
     axis, so the decode step routes them through the fused QKV megakernel
     (one launch per step instead of three — ``kernels/nmg_fused.py``);
     the packed gated-MLP ``wi`` likewise takes the fused projection+gate
-    launch."""
+    launch.
+
+    Only the weights named above change layout: every other leaf, the
+    biases of ``proj_bias`` / ``qkv_bias`` and the norms among them, stays
+    dense, and the model adds each bias to its n:m:g product."""
     sb = SparsityBuilder()
     sp = GroupedNMSparsifier(n, m, g, gr, sparse_dim=0)  # [K, N] weights
     sb.set_weight("*mlp.wi", sp, GroupedNMTensor)
@@ -238,6 +242,25 @@ def sparsify_for_serving(params, n: int = 1, m: int = 4, g: int = 16,
         for name in ("*attn.wq", "*attn.wk", "*attn.wv", "*attn.wo"):
             sb.set_weight(name, sp, GroupedNMTensor)
     return sb.sparsify_params(params)
+
+
+def window_masked_rows(cfg: ModelConfig, first: int, n: int) -> int:
+    """Cached rows the sliding window hides from the queries at positions
+    ``first .. first + n - 1``, summed over the local layers: a query at
+    position p sees keys ``p - window < j <= p``, so ``max(0, p + 1 -
+    window)`` of its causal rows are hidden in each local layer."""
+    W = cfg.local_window
+    layers = {"local": cfg.n_layers,
+              "alt_local_global": cfg.n_layers // 2}.get(cfg.layer_pattern,
+                                                          0)
+    if not W or not layers:
+        return 0
+
+    def upto(p):  # hidden rows of the queries at positions 0 .. p - 1
+        k = max(0, p - W)
+        return k * (k + 1) // 2
+
+    return layers * (upto(first + n) - upto(first))
 
 
 @dataclasses.dataclass
@@ -378,7 +401,10 @@ class ServeEngine:
         #: preemptions, rejected requests, peak concurrently-active slots,
         #: plus the SLO/fault loop's shed/timeout/retry/tier-switch counts,
         #: and the decode steps run by the chunk program (append buffer
-        #: over a read-only cache) against those on the one-step fallback.
+        #: over a read-only cache) against those on the one-step fallback,
+        #: and ``attn_window_masked_rows``: the cached rows the sliding
+        #: window hid from the queries the device ran, summed over local
+        #: layers, counted from lengths alone (:func:`window_masked_rows`).
         #: Reads/writes behave exactly like the plain dict this used to
         #: be; increases additionally mirror into the telemetry registry
         #: so a benchmark's registry snapshot includes engine stats.
@@ -386,7 +412,8 @@ class ServeEngine:
             {"deferred_admissions": 0, "preemptions": 0,
              "rejected": 0, "peak_active": 0, "shed": 0,
              "timeout": 0, "fault_retries": 0, "tier_switches": 0,
-             "decode_steps_chunked": 0, "decode_steps_single": 0},
+             "decode_steps_chunked": 0, "decode_steps_single": 0,
+             "attn_window_masked_rows": 0},
             REGISTRY.family("engine_stats",
                             help="engine scheduler counters"))
         # chunked decode falls back to single-step once a lone slot cannot
@@ -500,6 +527,8 @@ class ServeEngine:
                 logits = self.kv.write_prefill(self.params, prompt, slot)
             if self._latency is not None:
                 self._latency.observe_prefill(S, self._now() - t_pre)
+            self.stats["attn_window_masked_rows"] += window_masked_rows(
+                self.cfg, 0, S)
             if obs.enabled():
                 # the request's lifecycle row: time spent queued (arrival
                 # to admission)
@@ -812,6 +841,9 @@ class ServeEngine:
                                    pos)
             self.stats["decode_steps_chunked" if T > 1
                        else "decode_steps_single"] += T
+            self.stats["attn_window_masked_rows"] += sum(
+                window_masked_rows(self.cfg, int(self._pos[s]), T)
+                for s in active)
             with obs.span("engine.decode.fetch", "engine"):
                 # [T, max_slots] tokens or [max_slots, V] logits — one sync
                 out = np.asarray(out)

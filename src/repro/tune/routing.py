@@ -79,9 +79,19 @@ DEFAULT_SPMM_BLOCK_ELEMS = 1 << 22
 #: step, ~128 compressed positions per decompression window
 DEFAULT_GEMV_PALLAS = {"tm": 128, "target_depth": 128}
 
-#: default Pallas spmm config: 128 tokens per grid step, ~128 compressed
-#: positions per window, and the streamed schedule
-DEFAULT_SPMM_PALLAS = {"tn": 128, "target_depth": 128, "stream": True}
+#: default Pallas spmm config: 1024 tokens per grid step, ~256 compressed
+#: positions per window, on the grid schedule.  Each token tile decompresses
+#: the whole weight on the MXU again, so the tile sets how often that is
+#: paid, and the grid schedule holds one window of activations, not the
+#: ``[tile, K]`` slab the streamed one keeps resident (at K = 24576 that
+#: slab overran the kernel's scoped VMEM on a TPU v5e).  Timed on a v5e
+#: (1:4:16, gr=64, bf16) against the earlier default, 128-token tiles on
+#: the streamed schedule: StarCoder2-15B's FFN (6144 x 24576 and
+#: 24576 x 6144) at 2048 tokens 10.1 and 9.0 ms against 20.4 and 29.8
+#: (128-token tiles on the grid, the slab not fitting); bert-base's
+#: (768 x 3072 and 3072 x 768) within 0.03 ms either way at 32-256 tokens,
+#: and 0.75 and 0.73 ms against 1.13 and 0.88 at 2048
+DEFAULT_SPMM_PALLAS = {"tn": 1024, "target_depth": 256, "stream": False}
 
 #: decode megakernels fuse by default — eligibility (matching formats,
 #: decode-shaped M) is the kernels' business; the table can veto per bucket
@@ -253,7 +263,7 @@ def spmm_pallas_config(*, K: int, R: int, fmt: tuple, gr: int, dtype
                        ) -> tuple[dict, str]:
     """Pallas spmm config {tn, target_depth, stream} for this shape bucket.
     Exact-bucket hit, else the device-wide ``spmm_pallas`` override, else
-    the shipped default (streamed schedule)."""
+    the shipped default (windows on the grid)."""
     val, src = _lookup(
         shape_key("spmm_pallas", K=K, R=R, fmt=fmt, gr=gr, dtype=dtype),
         None,

@@ -22,7 +22,10 @@ FAMILIES = {
     "hymba-1.5b": 2e-3,       # hybrid window attn + SSM
     "gemma2-9b": 2e-3,        # local/global pairs, softcaps, ring cache
     "qwen1.5-4b": 2e-3,       # QKV bias
-    "starcoder2-15b": 2e-3,   # GQA + non-gated MLP
+    # GQA, LayerNorm, biased projections, non-gated MLP, and a window of 8
+    # that the 22-token sequences overrun: decode masks by window what the
+    # forward's chunks mask, in the same f32 arithmetic
+    "starcoder2-15b": 2e-3,
 }
 
 

@@ -117,3 +117,79 @@ def test_nm_mask_compiles(one_chip, n, m):
     x = jax.ShapeDtypeStruct((F, D), jnp.float32, sharding=one_chip)
     _compiles_to_kernel(
         lambda x: nm_mask_pallas(x, n, m, interpret=False), x)
+
+
+# -- StarCoder2-15B's FFN widths: d_model 6144, d_ff 24576 --------------------
+
+SC_D, SC_F = 6144, 24576
+
+#: largest double-buffered activation slab (tokens x K, bf16) the streamed
+#: schedule fits in the kernel's scoped VMEM on a v5e: K = 12288 at 128
+#: tokens (3 MiB) compiles, K = 24576 (6 MiB) does not
+STREAM_SLAB_BYTES = 4 << 20
+
+
+@pytest.mark.parametrize("K,R", [(SC_D, SC_F), (SC_F, SC_D)],
+                         ids=["c_fc", "c_proj"])
+def test_starcoder2_gemv_compiles(one_chip, K, R):
+    w = _weight(K, R, jnp.bfloat16, one_chip)
+    b = jax.ShapeDtypeStruct((K, 16), jnp.bfloat16, sharding=one_chip)
+    _compiles_to_kernel(
+        lambda w, b: nmg_gemv_pallas(w, b, out_dtype=jnp.bfloat16,
+                                     interpret=False),
+        w, b)
+
+
+@pytest.mark.parametrize("schedule", ["stream", "grid", "routed"])
+@pytest.mark.parametrize("M", [16, 1024, 3072])
+@pytest.mark.parametrize("K,R", [(SC_D, SC_F), (SC_F, SC_D)],
+                         ids=["c_fc", "c_proj"])
+def test_starcoder2_spmm_compiles(one_chip, K, R, M, schedule):
+    """Both schedules, and the config the routing picks by default; the
+    streamed one at the widest token tile whose resident activation slab
+    fits (128 tokens at K = 6144, 64 at K = 24576)."""
+    from repro.tune import routing
+
+    if schedule == "routed":
+        cfg, _ = routing.spmm_pallas_config(K=K, R=R, fmt=FMT[:3],
+                                            gr=FMT[3], dtype=jnp.bfloat16)
+    else:
+        stream = schedule == "stream"
+        tn = min(128, STREAM_SLAB_BYTES // (K * 2)) if stream \
+            else 128
+        cfg = {"stream": stream, "tn": tn, "target_depth": 128}
+    w = _weight(K, R, jnp.bfloat16, one_chip)
+    b = jax.ShapeDtypeStruct((K, M), jnp.bfloat16, sharding=one_chip)
+    _compiles_to_kernel(
+        lambda w, b: nmg_spmm_pallas(w, b, interpret=False, **cfg), w, b)
+
+
+@pytest.mark.parametrize("K,R", [(768, 3072), (3072, 768), (SC_D, SC_F),
+                                 (SC_F, SC_D)])
+def test_default_spmm_config_by_width(K, R):
+    """bert-base's and StarCoder2-15B's FFN widths take the one shipped
+    default, 1024-token tiles on the grid schedule, which holds no
+    activation slab (the streamed slab overruns VMEM at K = 24576)."""
+    from repro.tune import routing
+
+    cfg, src = routing.spmm_pallas_config(K=K, R=R, fmt=(1, 4, 16), gr=64,
+                                          dtype=jnp.bfloat16)
+    assert src == "default"
+    assert cfg == routing.DEFAULT_SPMM_PALLAS
+    assert not cfg["stream"]
+
+
+@pytest.mark.parametrize("M", [32, 256, 12288])
+@pytest.mark.parametrize("K,R", [(768, 3072), (3072, 768)],
+                         ids=["wi", "wo"])
+def test_bert_spmm_default_compiles(one_chip, K, R, M):
+    """The routed default at bert-base's FFN widths: decode slots, the
+    widest prompt bucket and a training batch of 32 x 384 tokens."""
+    from repro.tune import routing
+
+    cfg, _ = routing.spmm_pallas_config(K=K, R=R, fmt=FMT[:3], gr=FMT[3],
+                                        dtype=jnp.bfloat16)
+    w = _weight(K, R, jnp.bfloat16, one_chip)
+    b = jax.ShapeDtypeStruct((K, M), jnp.bfloat16, sharding=one_chip)
+    _compiles_to_kernel(
+        lambda w, b: nmg_spmm_pallas(w, b, interpret=False, **cfg), w, b)
