@@ -84,10 +84,10 @@ def _step_vmem(w, dtype, M: int, tm: int, target_depth: int,
                stream: bool) -> int:
     """VMEM bytes of one grid step of ``kernels/nmg_spmm.nmg_pallas_call``:
     double-buffered plan, value, activation and output blocks, the f32
-    accumulator, and the one-hot and decompressed-tile temporaries of one
-    window."""
+    accumulator and the wide tile scratch, and the one-hot, group-tile and
+    dot temporaries of one window."""
     from repro.kernels.nmg_spmm import (_groups_per_step, _sublanes,
-                                        windows)
+                                        tile_width, windows)
 
     n, m, g, gr = w.n, w.m, w.g, w.gr
     R_pad, nblocks, _ = w.val.shape[-3:]   # stacked layers lead
@@ -95,7 +95,7 @@ def _step_vmem(w, dtype, M: int, tm: int, target_depth: int,
     vb = jnp.dtype(dtype).itemsize
     sub = _sublanes(dtype)
     TM = min(-(-M // sub) * sub, max(sub, tm // sub * sub))
-    tg = _groups_per_step(G, gr, _sublanes(w.val.dtype))
+    W, tg = tile_width(gr), _groups_per_step(G, gr)
     wins = windows(n, m, g, nbn, target_depth)
     tc = max(c1 - c0 for c0, c1, _, _ in wins)
     tk = max(k1 - k0 for _, _, k0, k1 in wins)
@@ -104,10 +104,12 @@ def _step_vmem(w, dtype, M: int, tm: int, target_depth: int,
     blocks = (tg * 8 * lanes(step_tc) * 4                     # plan
               + tg * gr * lanes(step_tc) * vb                 # values
               + TM * lanes(step_tk) * vb                      # activations
-              + tg * TM * lanes(gr) * 4)                      # output
+              + TM * tg * gr * 4)                             # output
     temps = (tk * lanes(tc) * (4 + vb)                        # one-hot
-             + gr * lanes(tk) * (4 + vb)                      # dense tile
-             + tg * TM * lanes(gr) * 4)                       # accumulator
+             + gr * lanes(tk) * (4 + vb)                      # group tile
+             + W * lanes(tk) * vb                             # wide tile
+             + TM * W * 4                                     # one dot
+             + TM * tg * gr * 4)                              # accumulator
     return 2 * blocks + temps
 
 

@@ -18,23 +18,34 @@ TPU adaptation of the paper's AVX microkernel:
   from an iota and the group's gather plan (``SpmmPlan.cols``, the
   absolute K row of every stored value), scatters the compressed values
   into a dense ``[gr, window K]`` tile with one matmul (``val @ S^T`` —
-  exact, every entry is one value or zero), and contracts that tile with
-  the activations' window of K.  Weights are read from HBM compressed
-  (``n/m`` of the dense bytes plus the plan); the MXU does dense work on
-  the decompressed tile.
+  exact, every entry is one value or zero).  Weights are read from HBM
+  compressed (``n/m`` of the dense bytes plus the plan); the MXU does
+  dense work on the decompressed tile.
+* The useful product runs on whole 128-lane tiles.  Consecutive groups'
+  decompressed tiles are written side by side into one VMEM scratch tile
+  ``[W, window K]``, ``W = tile_width(gr) = lcm(gr, 128)`` (two groups at
+  gr=64), and the activations' window of K is contracted against all of
+  it in one dot, ``[TM, tk] x [W, tk]^T -> [TM, W]``: a ``gr``-wide dot
+  would leave half of the 128x128 MXU empty at gr=64.  A lone last group
+  (``Gr`` not a multiple of ``W / gr``) gets zero partner rows.
 * Windows are static: a whole number of chunks (chunk position p carries
   pattern ``p // g``, so a chunk maps onto a contiguous K range) and a
   multiple of the 128-lane tile, so every slice in the kernel is static
   and aligned.  ``target_depth`` widens the window.
-* Every output group is computed by the same sequence of window dots, so
-  a group's result does not depend on how many groups share a grid step,
-  on the other groups of a launch (fused QKV), or on the schedule.
+* Every output group is computed by the same sequence of window dots, all
+  of one shape ``[TM, tk] x [W, tk]^T``, and an output column reads only
+  its own row of the tile, so a group's result does not depend on how
+  many groups share a grid step, on its partner in the tile (another
+  group of the launch, as in fused QKV, or the zero pad), or on the
+  schedule.
 
 Storage is read through free reshapes of the layout's arrays: ``val``
 ``[R_pad, nblocks, n]`` as a lane-dense ``[R_pad, nblocks*n]`` and the plan
-``cols [Gr, nblocks*n]`` as ``[Gr, 1, nblocks*n]``.  The output is written
-per group, ``[Gr, M_pad, gr]`` (a ``gr``-wide lane block equals the array's
-last dim, so any ``gr`` is legal), and transposed to ``[M, R]`` by XLA.
+``cols [Gr, nblocks*n]`` as ``[Gr, 1, nblocks*n]``, each split into its
+sources (one, or the u and v halves of a gated pair).  The f32
+accumulator is ``[tiles, TM, W]``, lane-dense, and the output is written
+in the ``[M_pad, R_pad]`` order the callers read, ``W``-lane tiles at a
+time: no transpose follows the call.
 
 Two schedules:
 
@@ -50,10 +61,13 @@ Two schedules:
   schedule does.
 
 Both accumulate each group's windows in the same order, so their outputs
-agree bitwise.  VMEM per step at bert-base widths (bf16, 1:4:16, gr=64,
-K=3072, 4 groups, TM=128): activation slab 0.75 MiB, value block 0.38 MiB,
-plan 0.1 MiB, each double-buffered, plus a 0.25 MiB accumulator and the
-one-hot and tile temporaries — well inside v5e's 16 MiB scoped limit.
+agree bitwise.  VMEM per step at the shipped default (bf16, 1:4:16, gr=64,
+grid schedule, TM=1024, 256-position windows, so a window is 1024 columns
+of K whatever K is): activation window 2 MiB, f32 output block [1024, 256]
+1 MiB, value block 0.13 MiB and plan 0.03 MiB, each double-buffered, plus
+the 1 MiB accumulator [2, 1024, 128], the 0.25 MiB [128, 1024] tile and the
+one-hot, group-tile and dot temporaries (~2.3 MiB) — ~10 MiB of v5e's
+16 MiB scoped limit.
 """
 
 from __future__ import annotations
@@ -67,6 +81,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.layouts import GroupedNMTensor
+from repro.obs.registry import REGISTRY
 
 __all__ = ["nmg_spmm_pallas", "nmg_pallas_call", "nmg_rows", "pad_k",
            "storage_views", "windows"]
@@ -79,6 +94,17 @@ _LANES = 128
 _ROWS_PER_STEP = 256
 
 _NT = (((1,), (1,)), ((), ()))  # contract the last dims of both operands
+
+# (kernel, path) -> number of traces routed there.  A ``repro.obs``
+# registry family: same Counter semantics at every call site, but the
+# counts join the unified telemetry snapshot and each routing decision
+# becomes a timestamped ``kernel_route`` event on the kernel track when
+# the flight recorder is enabled.  ``kernels/ops.py`` counts its routes
+# here; every launch adds ``("nmg_pallas", "w<tile_width>")``.
+_KERNEL_COUNTS = REGISTRY.family(
+    "kernel_routes",
+    help="trace-time kernel routing: (kernel, path) -> traces",
+    trace_as="kernel_route", track="kernel")
 
 
 def windows(n: int, m: int, g: int, nbn: int, target_depth: int) -> list:
@@ -95,27 +121,32 @@ def windows(n: int, m: int, g: int, nbn: int, target_depth: int) -> list:
             for c0 in range(0, nbn, tc)]
 
 
-def _window_dot(cols, vals, x, k0, cdt, precision):
-    """One window of one group: decompress ``vals`` [gr, tc] into a dense
+def tile_width(gr: int) -> int:
+    """Output lanes of one useful dot: whole fiber groups side by side,
+    ``lcm(gr, 128)`` (two groups at gr=64, one at gr=128)."""
+    return math.lcm(gr, _LANES)
+
+
+def _decompress(cols, vals, k0, tk, cdt, precision):
+    """One window of one group: scatter ``vals`` [gr, tc] into a dense
     [gr, tk] tile through the one-hot of ``cols`` [1, tc] (absolute K rows,
-    window starting at ``k0``), then contract with ``x`` [TM, tk]."""
-    tk, tc = x.shape[1], cols.shape[1]
+    window starting at ``k0``)."""
+    tc = cols.shape[1]
     rows = jax.lax.broadcasted_iota(jnp.int32, (tk, tc), 0) + k0
     onehot = jnp.where(rows == cols, 1.0, 0.0).astype(cdt)      # [tk, tc]
     tile = jax.lax.dot_general(vals.astype(cdt), onehot, _NT,
                                precision=precision,
                                preferred_element_type=jnp.float32)
-    return jax.lax.dot_general(x, tile.astype(cdt), _NT,
-                               precision=precision,
-                               preferred_element_type=jnp.float32)
+    return tile.astype(cdt)
 
 
-def _kernel(*refs, nsrc, wins, gr, tg, k_steps, tk_step, cdt, precision,
-            epilogue):
+def _kernel(*refs, nsrc, wins, gr, per, tw, G, k_steps, tk_step, cdt,
+            precision, epilogue):
     cols_refs, val_refs = refs[:nsrc], refs[nsrc:2 * nsrc]
     x_ref, o_ref = refs[2 * nsrc], refs[2 * nsrc + 1]
-    acc_refs = refs[2 * nsrc + 2:]
-    ki = pl.program_id(2)
+    acc_refs, tile_ref = refs[2 * nsrc + 2:-1], refs[-1]
+    gi, ki = pl.program_id(1), pl.program_id(2)
+    W = per * gr
 
     @pl.when(ki == 0)
     def _init():
@@ -124,23 +155,51 @@ def _kernel(*refs, nsrc, wins, gr, tg, k_steps, tk_step, cdt, precision,
 
     kbase = ki * tk_step
     for cols_ref, val_ref, acc_ref in zip(cols_refs, val_refs, acc_refs):
-        def group(gi, carry, cols_ref=cols_ref, val_ref=val_ref,
-                  acc_ref=acc_ref):
-            r0 = pl.multiple_of(gi * gr, gr)
-            acc = acc_ref[gi]
+        def wide(t, carry, cols_ref=cols_ref, val_ref=val_ref,
+                 acc_ref=acc_ref):
+            acc = acc_ref[t]                                    # [TM, W]
             for c0, c1, k0, k1 in wins:
-                acc = acc + _window_dot(
-                    cols_ref[gi, :, c0:c1], val_ref[pl.ds(r0, gr), c0:c1],
-                    x_ref[:, k0:k1], kbase + k0, cdt, precision)
-            acc_ref[gi] = acc
+                tk = k1 - k0
+
+                def fill(j, carry, c0=c0, c1=c1, k0=k0, tk=tk):
+                    q, rows = t * per + j, pl.ds(j * gr, gr)
+
+                    def put():
+                        tile_ref[rows, :tk] = _decompress(
+                            cols_ref[q, :, c0:c1],
+                            val_ref[pl.ds(pl.multiple_of(q * gr, gr), gr),
+                                    c0:c1],
+                            kbase + k0, tk, cdt, precision)
+
+                    if G % per == 0:
+                        put()
+                    else:                 # a lone last group: zero partner
+                        real = gi * tw * per + q < G
+                        pl.when(real)(put)
+
+                        @pl.when(jnp.logical_not(real))
+                        def _pad():
+                            tile_ref[rows, :tk] = jnp.zeros((gr, tk), cdt)
+                    return carry
+
+                if per <= 8:              # static rows in the tile
+                    for j in range(per):
+                        fill(j, 0)
+                else:
+                    jax.lax.fori_loop(0, per, fill, 0)
+                acc = acc + jax.lax.dot_general(
+                    x_ref[:, k0:k1], tile_ref[:, :tk], _NT,
+                    precision=precision, preferred_element_type=jnp.float32)
+            acc_ref[t] = acc
             return carry
 
-        jax.lax.fori_loop(0, tg, group, 0)
+        jax.lax.fori_loop(0, tw, wide, 0)
 
     @pl.when(ki == k_steps - 1)
     def _done():
-        out = epilogue(*(acc_ref[...] for acc_ref in acc_refs))
-        o_ref[...] = out.astype(o_ref.dtype)
+        for t in range(tw):
+            out = epilogue(*(acc_ref[t] for acc_ref in acc_refs))
+            o_ref[:, t * W:(t + 1) * W] = out.astype(o_ref.dtype)
 
 
 def _sublanes(*dtypes) -> int:
@@ -148,11 +207,17 @@ def _sublanes(*dtypes) -> int:
     return 32 // min(jnp.dtype(d).itemsize for d in dtypes)
 
 
-def _groups_per_step(G: int, gr: int, sub: int) -> int:
-    for d in range(1, G + 1):
-        if G % d == 0 and d * gr >= _ROWS_PER_STEP and (d * gr) % sub == 0:
-            return d
-    return G
+def _groups_per_step(G: int, gr: int) -> int:
+    """Fiber groups one grid step covers: whole ``tile_width`` tiles, the
+    fewest that reach ``_ROWS_PER_STEP`` output lanes and divide the
+    ``ceil(G / per)`` tiles of ``per`` groups, else all of them."""
+    W = tile_width(gr)
+    per = W // gr
+    tiles = -(-G // per)
+    for d in range(1, tiles + 1):
+        if tiles % d == 0 and d * W >= _ROWS_PER_STEP:
+            return d * per
+    return tiles * per
 
 
 def nmg_pallas_call(val2, cols3, x, *, n: int, m: int, g: int, gr: int,
@@ -179,6 +244,11 @@ def nmg_pallas_call(val2, cols3, x, *, n: int, m: int, g: int, gr: int,
     TM = min(-(-M // sub) * sub, max(sub, tm // sub * sub))
     M_pad = -(-M // TM) * TM
     x = jnp.pad(x.astype(cdt), ((0, M_pad - M), (0, 0)))
+    if interpret:
+        # when the interpreted grid and loops collapse to one step, XLA's
+        # CPU backend folds a transposed ``x`` into the bf16 dot, a form
+        # its dot runtime refuses; the barrier keeps ``x`` as it is
+        x = jax.lax.optimization_barrier(x)
 
     wins = windows(n, m, g, nbn, target_depth)
     if not stream and len(wins) > 1 and nbn % (wins[0][1] - wins[0][0]) == 0:
@@ -186,8 +256,10 @@ def nmg_pallas_call(val2, cols3, x, *, n: int, m: int, g: int, gr: int,
         k_steps, step_wins = len(wins), [(0, tc, 0, tk)]
     else:
         k_steps, tc, tk, step_wins = 1, nbn, K_pad, wins
-    tg = _groups_per_step(G, gr, _sublanes(val2.dtype))
-    offs = [0, G // tg][:nsrc]
+    W = tile_width(gr)
+    per, tg = W // gr, _groups_per_step(G, gr)
+    steps = -(-G // tg)
+    _KERNEL_COUNTS[("nmg_pallas", f"w{W}")] += 1
 
     if gate_act is None:
         epilogue = lambda acc: acc                      # noqa: E731
@@ -195,23 +267,32 @@ def nmg_pallas_call(val2, cols3, x, *, n: int, m: int, g: int, gr: int,
         def epilogue(u, v):
             return gate_act(u.astype(out_dtype)) * v.astype(out_dtype)
 
+    # each source (the u and v halves of a gated pair) as its own slab of
+    # groups: free reshapes, and a lone last group's tile stays in its half
+    val3 = val2.reshape(nsrc, G * gr, nbn)
+    cols4 = cols3.reshape(nsrc, G, 1, nbn)
+    tk_max = max(k1 - k0 for _, _, k0, k1 in step_wins)
     out = pl.pallas_call(
-        functools.partial(_kernel, nsrc=nsrc, wins=step_wins, gr=gr, tg=tg,
-                          k_steps=k_steps, tk_step=tk, cdt=cdt,
-                          precision=precision, epilogue=epilogue),
-        grid=(M_pad // TM, G // tg, k_steps),
+        functools.partial(_kernel, nsrc=nsrc, wins=step_wins, gr=gr,
+                          per=per, tw=tg // per, G=G, k_steps=k_steps,
+                          tk_step=tk, cdt=cdt, precision=precision,
+                          epilogue=epilogue),
+        grid=(M_pad // TM, steps, k_steps),
         in_specs=(
-            [pl.BlockSpec((tg, 1, tc), lambda mi, gi, ki, o=o: (gi + o, 0, ki))
-             for o in offs]
-            + [pl.BlockSpec((tg * gr, tc), lambda mi, gi, ki, o=o: (gi + o, ki))
-               for o in offs]
+            [pl.BlockSpec((None, tg, 1, tc),
+                          lambda mi, gi, ki, s=s: (s, gi, 0, ki))
+             for s in range(nsrc)]
+            + [pl.BlockSpec((None, tg * gr, tc),
+                            lambda mi, gi, ki, s=s: (s, gi, ki))
+               for s in range(nsrc)]
             + [pl.BlockSpec((TM, tk), lambda mi, gi, ki: (mi, ki))]),
-        out_specs=pl.BlockSpec((tg, TM, gr), lambda mi, gi, ki: (gi, mi, 0)),
-        out_shape=jax.ShapeDtypeStruct((G, M_pad, gr), out_dtype),
-        scratch_shapes=[pltpu.VMEM((tg, TM, gr), jnp.float32)] * nsrc,
+        out_specs=pl.BlockSpec((TM, tg * gr), lambda mi, gi, ki: (mi, gi)),
+        out_shape=jax.ShapeDtypeStruct((M_pad, steps * tg * gr), out_dtype),
+        scratch_shapes=([pltpu.VMEM((tg // per, TM, W), jnp.float32)] * nsrc
+                        + [pltpu.VMEM((W, tk_max), cdt)]),
         interpret=interpret,
-    )(*[cols3] * nsrc, *[val2] * nsrc, x)
-    return out.transpose(1, 0, 2).reshape(M_pad, G * gr)[:M]
+    )(*[cols4] * nsrc, *[val3] * nsrc, x)
+    return out[:M, :G * gr]
 
 
 def storage_views(a: GroupedNMTensor):

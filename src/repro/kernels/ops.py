@@ -43,7 +43,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.layouts import GroupedNMTensor
-from repro.obs.registry import REGISTRY as _REGISTRY
 from repro.kernels import ref as kref
 from repro.tune import routing
 from repro.kernels.fused_sparse_matmul import matmul_threshold_pallas
@@ -57,7 +56,7 @@ from repro.kernels.nmg_fused import (
     nmg_qkv_pallas,
 )
 from repro.kernels.nmg_gemv import nmg_gemv_pallas
-from repro.kernels.nmg_spmm import nmg_spmm_pallas
+from repro.kernels.nmg_spmm import _KERNEL_COUNTS, nmg_spmm_pallas
 
 __all__ = [
     "on_tpu",
@@ -92,16 +91,6 @@ DECODE_M_MAX = routing.DEFAULT_DECODE_M_MAX
 #: without its group-at-a-time serialization; tuned per device via
 #: ``spmm_block_elems`` table entries
 _SPMM_BLOCK_ELEMS = routing.DEFAULT_SPMM_BLOCK_ELEMS
-
-# (kernel, path) -> number of traces routed there.  A ``repro.obs``
-# registry family: same Counter semantics at every call site, but the
-# counts join the unified telemetry snapshot and each routing decision
-# becomes a timestamped ``kernel_route`` event on the kernel track when
-# the flight recorder is enabled.
-_KERNEL_COUNTS = _REGISTRY.family(
-    "kernel_routes",
-    help="trace-time kernel routing: (kernel, path) -> traces",
-    trace_as="kernel_route", track="kernel")
 
 
 def kernel_counters() -> dict:

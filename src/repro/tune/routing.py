@@ -81,10 +81,14 @@ DEFAULT_GEMV_PALLAS = {"tm": 128, "target_depth": 128}
 
 #: default Pallas spmm config: 1024 tokens per grid step, ~256 compressed
 #: positions per window, on the grid schedule.  Each token tile decompresses
-#: the whole weight on the MXU again, so the tile sets how often that is
-#: paid, and the grid schedule holds one window of activations, not the
-#: ``[tile, K]`` slab the streamed one keeps resident (at K = 24576 that
-#: slab overran the kernel's scoped VMEM on a TPU v5e).  Timed on a v5e
+#: the whole weight on the MXU again, but that is the smaller cost: a fit of
+#: T = a * tiles + b to StarCoder2-15B's c_fc at 2048 tokens (20.4 ms on 16
+#: tiles, 10.1 on 2) gives a ~0.74 ms a pass over the weight and b ~8.6 ms
+#: that scales with the tokens, so decompression is ~1.5 of the 10.1 ms at
+#: 1024-token tiles.  The grid schedule holds one window of activations,
+#: not the ``[tile, K]`` slab the streamed one keeps resident (at
+#: K = 24576 that slab overran the kernel's scoped VMEM on a TPU v5e).
+#: Timed on a v5e, before the kernel's 128-lane tiles
 #: (1:4:16, gr=64, bf16) against the earlier default, 128-token tiles on
 #: the streamed schedule: StarCoder2-15B's FFN (6144 x 24576 and
 #: 24576 x 6144) at 2048 tokens 10.1 and 9.0 ms against 20.4 and 29.8

@@ -24,7 +24,9 @@ import pytest
 from repro.core import nmg
 from repro.core.layouts import nm_patterns
 from repro.kernels import ops as kops
+from repro.kernels import nmg_spmm as nmgk
 from repro.kernels import ref as kref
+from repro.kernels.nmg_fused import nmg_ffn_pallas, nmg_qkv_pallas
 from repro.kernels.nmg_gemv import nmg_gemv_pallas
 from repro.kernels.nmg_spmm import nmg_spmm_pallas
 
@@ -333,3 +335,130 @@ def test_nmg_linear_straddles_decode_m_max(delta):
     assert y.dtype == x.dtype and y.shape == (rows, 64)
     np.testing.assert_allclose(np.asarray(y), np.asarray(x @ wt.to_dense()),
                                rtol=1e-3, atol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# wide tiles: consecutive fiber groups share one 128-lane useful dot
+# ---------------------------------------------------------------------------
+
+WIDE_FMT = (1, 4, 16)   # the serving pattern; K = 1024 gives two windows
+
+
+def _wide_weight(R, gr, K=1024, key=KEY):
+    w = jax.random.normal(key, (R, K))
+    return nmg.dense_to_grouped_nm(w, *WIDE_FMT, gr=gr)
+
+
+@pytest.mark.pallas_interpret
+@pytest.mark.parametrize("stream", [True, False], ids=["stream", "grid"])
+@pytest.mark.parametrize("gr", [16, 32, 64, 128])
+def test_wide_tile_matches_oracle_by_gr(gr, stream):
+    """Every gr the serving layouts use, on both schedules, with two
+    windows a group: 384 output rows are 24 to 3 fiber groups, 8 to 1 of
+    them to a 128-lane tile."""
+    t = _wide_weight(384, gr)
+    b = jax.random.normal(jax.random.PRNGKey(1), (1024, 40))
+    out = nmg_spmm_pallas(t, b, interpret=True, stream=stream, tn=16)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(kref.nmg_spmm_ref(t, b)),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.pallas_interpret
+@pytest.mark.parametrize("stream", [True, False], ids=["stream", "grid"])
+def test_lone_last_group_matches_oracle(stream):
+    """Five groups of 64 rows: the fifth fills half of its tile, the other
+    half is the zero pad, and nothing of the pad reaches the output."""
+    t = _wide_weight(5 * 64, 64)
+    b = jax.random.normal(jax.random.PRNGKey(1), (1024, 24))
+    out = nmg_spmm_pallas(t, b, interpret=True, stream=stream, tn=16)
+    assert out.shape == (320, 24)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(kref.nmg_spmm_ref(t, b)),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.pallas_interpret
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("stream", [True, False], ids=["stream", "grid"])
+def test_group_bits_do_not_depend_on_its_partner(stream, dtype):
+    """Group 4 of a six-group weight shares its tile with group 5; cut to
+    five groups, it shares it with the zero pad.  Its columns agree bit
+    for bit, and so do those of groups 0-3."""
+    t = _wide_weight(6 * 64, 64)
+    val2, cols3 = nmgk.storage_views(t)
+    x = jax.random.normal(jax.random.PRNGKey(1), (24, 1024)).astype(dtype)
+    val2 = val2.astype(dtype)
+    kw = dict(n=t.n, m=t.m, g=t.g, gr=64, out_dtype=jnp.float32, tm=16,
+              target_depth=128, stream=stream, interpret=True)
+    paired = nmgk.nmg_pallas_call(val2, cols3, nmgk.pad_k(t, x), **kw)
+    lone = nmgk.nmg_pallas_call(val2[:320], cols3[:5], nmgk.pad_k(t, x),
+                                **kw)
+    assert paired.shape == (24, 384) and lone.shape == (24, 320)
+    np.testing.assert_array_equal(np.asarray(lone),
+                                  np.asarray(paired[:, :320]))
+
+
+@pytest.mark.pallas_interpret
+@pytest.mark.parametrize("out_dtype", [jnp.float32, jnp.bfloat16])
+def test_fused_qkv_segment_boundary_inside_a_tile(out_dtype):
+    """q of three groups, k and v of one: q's third group and k share a
+    128-lane tile, and v sits beside the pad.  Fused equals the three
+    launches bit for bit, and the oracle."""
+    ks = jax.random.split(KEY, 3)
+    ws = tuple(nmg.dense_to_grouped_nm(jax.random.normal(k, (256, R)),
+                                       *WIDE_FMT, gr=64, sparse_dim=0)
+               for k, R in zip(ks, (192, 64, 64)))
+    b = jax.random.normal(jax.random.PRNGKey(1), (256, 4))
+    fused = nmg_qkv_pallas(ws, b, out_dtype=out_dtype, interpret=True)
+    for w, f, want in zip(ws, fused, kref.nmg_qkv_ref(ws, b)):
+        s = nmg_gemv_pallas(w, b, out_dtype=out_dtype, interpret=True)
+        np.testing.assert_array_equal(np.asarray(f), np.asarray(s))
+        np.testing.assert_allclose(np.asarray(f, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.pallas_interpret
+def test_fused_ffn_odd_groups_per_half():
+    """A gated pair whose halves hold three groups each: the u tile and
+    its v partner both end on a lone group, and the fused epilogue still
+    pairs group i with group i + 3."""
+    w = nmg.dense_to_grouped_nm(jax.random.normal(KEY, (256, 384)),
+                                *WIDE_FMT, gr=64, sparse_dim=0)
+    b = jax.random.normal(jax.random.PRNGKey(2), (256, 4))
+    hh = nmg_gemv_pallas(w, b, out_dtype=jnp.float32, interpret=True)
+    u, v = jnp.split(hh.T, 2, axis=-1)
+    seq = (jax.nn.silu(u) * v).T
+    fused = nmg_ffn_pallas(w, b, act="silu", out_dtype=jnp.float32,
+                           interpret=True)
+    np.testing.assert_array_equal(np.asarray(fused), np.asarray(seq))
+
+
+@pytest.mark.parametrize("gr,width", [(16, 128), (64, 128), (128, 128),
+                                      (256, 256)])
+def test_wide_tile_counter_engages(gr, width):
+    """Every launch records its dot width in ``kernel_routes`` at trace
+    time."""
+    t = _wide_weight(2 * gr, gr, K=256)
+    val2, cols3 = nmgk.storage_views(t)
+    x = jnp.zeros((8, 256), jnp.float32)
+    kops.reset_kernel_counters()
+    nmgk.nmg_pallas_call(val2, cols3, x, n=t.n, m=t.m, g=t.g, gr=gr,
+                         out_dtype=jnp.float32, tm=8, target_depth=128,
+                         stream=True, interpret=True)
+    assert kops.kernel_counters() == {("nmg_pallas", f"w{width}"): 1}
+
+
+def test_nmg_rows_writes_rows_with_no_transpose_after_the_kernel():
+    """The kernel writes [M, R] in the order its callers read: nothing
+    after the ``pallas_call`` transposes its output."""
+    t = _wide_weight(256, 64, K=256)
+    x = jnp.zeros((16, 256), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda t, x: nmgk.nmg_rows(
+        t, x, out_dtype=jnp.bfloat16, tm=16, target_depth=128, stream=True,
+        interpret=True))(t, x).jaxpr
+    prims = [e.primitive.name for e in jaxpr.eqns]
+    assert "pallas_call" in prims
+    after = prims[prims.index("pallas_call") + 1:]
+    assert "transpose" not in after, after

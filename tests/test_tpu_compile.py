@@ -164,6 +164,22 @@ def test_starcoder2_spmm_compiles(one_chip, K, R, M, schedule):
         lambda w, b: nmg_spmm_pallas(w, b, interpret=False, **cfg), w, b)
 
 
+@pytest.mark.parametrize("K,R", [(SC_D, SC_F), (SC_F, SC_D)],
+                         ids=["c_fc", "c_proj"])
+def test_starcoder2_spmm_default_compiles_at_2048(one_chip, K, R):
+    """The middle prompt bucket on the routed default (1024 and 3072 are
+    in ``test_starcoder2_spmm_compiles``): two 1024-token tiles, the
+    [2, 1024, 128] accumulator and the [128, 1024] tile scratch."""
+    from repro.tune import routing
+
+    cfg, _ = routing.spmm_pallas_config(K=K, R=R, fmt=FMT[:3], gr=FMT[3],
+                                        dtype=jnp.bfloat16)
+    w = _weight(K, R, jnp.bfloat16, one_chip)
+    b = jax.ShapeDtypeStruct((K, 2048), jnp.bfloat16, sharding=one_chip)
+    _compiles_to_kernel(
+        lambda w, b: nmg_spmm_pallas(w, b, interpret=False, **cfg), w, b)
+
+
 @pytest.mark.parametrize("K,R", [(768, 3072), (3072, 768), (SC_D, SC_F),
                                  (SC_F, SC_D)])
 def test_default_spmm_config_by_width(K, R):
@@ -193,3 +209,46 @@ def test_bert_spmm_default_compiles(one_chip, K, R, M):
     b = jax.ShapeDtypeStruct((K, M), jnp.bfloat16, sharding=one_chip)
     _compiles_to_kernel(
         lambda w, b: nmg_spmm_pallas(w, b, interpret=False, **cfg), w, b)
+
+
+#: a weight of 13 fiber groups (832 rows at gr=64): the last 128-lane tile
+#: holds one group beside the zero pad, and the last value block reaches
+#: past the array
+ODD_R = 13 * 64
+
+
+@pytest.mark.parametrize("kernel", ["gemv", "spmm_stream", "spmm_routed",
+                                    "qkv_boundary", "ffn_odd_half"])
+def test_odd_group_count_compiles(one_chip, kernel):
+    """Lone last groups in every kernel: a GEMV and an SpMM over 13 groups,
+    a fused QKV whose q/k boundary falls inside a tile (3 + 1 + 1 groups),
+    and a gated pair of 3 groups a half."""
+    from repro.tune import routing
+
+    M = 16 if kernel in ("gemv", "qkv_boundary", "ffn_odd_half") else 2048
+    b = jax.ShapeDtypeStruct((D, M), jnp.bfloat16, sharding=one_chip)
+    if kernel == "qkv_boundary":
+        ws = tuple(_weight(D, R, jnp.bfloat16, one_chip)
+                   for R in (192, 64, 64))
+        _compiles_to_kernel(
+            lambda ws, b: nmg_qkv_pallas(ws, b, out_dtype=jnp.bfloat16,
+                                         interpret=False), ws, b)
+        return
+    if kernel == "ffn_odd_half":
+        w = _weight(D, 2 * 192, jnp.bfloat16, one_chip)
+        _compiles_to_kernel(
+            lambda w, b: nmg_ffn_pallas(w, b, act="silu",
+                                        out_dtype=jnp.bfloat16,
+                                        interpret=False), w, b)
+        return
+    w = _weight(D, ODD_R, jnp.bfloat16, one_chip)
+    if kernel == "gemv":
+        fn = lambda w, b: nmg_gemv_pallas(w, b, out_dtype=jnp.bfloat16,  # noqa: E731
+                                          interpret=False)
+    else:
+        cfg = {"stream": True, "tn": 128, "target_depth": 128}
+        if kernel == "spmm_routed":
+            cfg, _ = routing.spmm_pallas_config(
+                K=D, R=ODD_R, fmt=FMT[:3], gr=FMT[3], dtype=jnp.bfloat16)
+        fn = lambda w, b: nmg_spmm_pallas(w, b, interpret=False, **cfg)  # noqa: E731
+    _compiles_to_kernel(fn, w, b)
